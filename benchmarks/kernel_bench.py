@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def timeit(fn, *args, iters=3):
@@ -32,6 +33,7 @@ def timeit(fn, *args, iters=3):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()

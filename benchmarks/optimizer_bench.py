@@ -41,6 +41,7 @@ from repro.core import adamw, lamb, lars, packing, sgd
 from repro.core.optim_base import PackedGrads
 from repro.kernels import ops
 from repro.kernels.introspect import count_pallas_launches
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_tree(n_layers: int, d: int, key) -> dict:
@@ -331,6 +332,7 @@ def bench_zero_sharding(params, stacked, batch_n: int, *, iters: int,
     from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.train import TrainPipeline
 
@@ -339,7 +341,7 @@ def bench_zero_sharding(params, stacked, batch_n: int, *, iters: int,
         print("zero_sharding: skipped (needs >= 2 devices; run under "
               "--xla_force_host_platform_device_count=8)", flush=True)
         return None
-    mesh = jax.make_mesh((ndev, 1), ("data", "model"))
+    mesh = make_mesh((ndev, 1), ("data", "model"))
     out: dict = {"ndev": ndev, "mesh": f"{ndev}x1",
                  "slot_bytes_per_device": {}}
 
@@ -424,6 +426,7 @@ def bench_zero_sharding(params, stacked, batch_n: int, *, iters: int,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--out", default="BENCH_optimizer.json",

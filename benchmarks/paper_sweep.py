@@ -52,8 +52,10 @@ from repro.core import lars, schedules
 from repro.experiments import GridRunner, GridSpec, aggregate
 from repro.experiments.spec import (INIT_LR, LR_DECAY, MOMENTUM,
                                     TRUST_COEF, WEIGHT_DECAY)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import TrainPipeline
+from repro.train.pipeline import compiled_peak_bytes
 
 
 # ------------------------------------------------- execution-pipeline bench
@@ -86,13 +88,7 @@ def accum_bench(*, micro_batch: int = 256, accum_steps: int = 8,
         pipe = TrainPipeline(model, _bench_opt(), cfg,
                              accum_steps=accum_steps, precision=precision)
         state = pipe.init_state(jax.random.key(0))
-        peak = None
-        try:
-            mem = pipe.lower(state, batch).compile().memory_analysis()
-            peak = int(mem.temp_size_in_bytes + mem.argument_size_in_bytes
-                       + mem.output_size_in_bytes)
-        except Exception:
-            pass  # backend without memory analysis: report timing only
+        peak = compiled_peak_bytes(pipe.lower(state, batch).compile())
         state, m = pipe(state, batch)          # compile + warmup
         jax.block_until_ready(m["loss"])
         t0 = time.perf_counter()
@@ -135,6 +131,7 @@ def accum_bench(*, micro_batch: int = 256, accum_steps: int = 8,
 # ----------------------------------------------------------------- sweep
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="tiny sweep for CI (seconds, not minutes)")

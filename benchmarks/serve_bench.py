@@ -39,6 +39,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import DecodeEngine, ServeEngine
 from repro.serve.report import (ServeScenario, format_scenarios,
@@ -229,6 +230,7 @@ def run_library(model, params, cfg, args) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--arch", default="qwen3-14b")

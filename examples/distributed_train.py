@@ -25,13 +25,14 @@ from repro.core import lars                   # noqa: E402
 from repro.data import TokenTaskConfig, token_batches  # noqa: E402
 from repro.distributed import (batch_pspecs, state_pspecs,  # noqa: E402
                                tree_named)
+from repro.launch.mesh import make_mesh       # noqa: E402
 from repro.models import build_model          # noqa: E402
 from repro.train import create_train_state, make_train_step  # noqa: E402
 
 
 def main() -> None:
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("qwen3-14b").reduced()
     model = build_model(cfg)
     opt = lars(0.05, trust_coefficient=0.01)
@@ -54,7 +55,7 @@ def main() -> None:
           f"per-device shard {wq.addressable_shards[0].data.shape}")
 
     task = TokenTaskConfig(vocab_size=cfg.vocab_size, branching=2, seed=0)
-    with mesh:
+    with jax.set_mesh(mesh):
         for i, t in enumerate(token_batches(task, batch=8, seq_len=32)):
             batch = {"tokens": jax.device_put(
                 jnp.asarray(t[:, :32]), tree_named(mesh, bspecs)["tokens"])}

@@ -51,7 +51,9 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
+from repro.distributed.constrain import current_mesh
 from repro.treepath import path_str
 
 Pytree = Any
@@ -250,23 +252,6 @@ def adapt_mask(layout: PackedLayout) -> jnp.ndarray:
 
 # ---------------------------------------------------------- pack / unpack
 
-def _ambient_mesh():
-    """The legacy ``with mesh:`` context's mesh, or None.
-
-    Limitation (jax 0.4.x): this is the only place the packed
-    substrate can discover a mesh at trace time — tracing a packed
-    update under jit with ``in_shardings=NamedSharding(...)`` but NO
-    ambient mesh skips every constraint below. Sharded runs must either
-    trace inside ``with mesh:`` (what this repo's pjit entry points do)
-    or use the per-leaf tree layout (``opt.init(params)``), which
-    shards cleanly leaf-for-leaf.
-    """
-    from jax.interpreters import pxla
-
-    mesh = pxla.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
 def _replicate_in_mesh(x: jnp.ndarray) -> jnp.ndarray:
     """Pin ``x`` to fully-replicated when tracing under an ambient mesh.
 
@@ -277,14 +262,15 @@ def _replicate_in_mesh(x: jnp.ndarray) -> jnp.ndarray:
     silently wrong trust ratio under pjit). The packed substrate's
     contract is an explicitly-stated optimizer region sharding; GSPMD
     then inserts the collectives exactly once, at the constraint.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec
 
-    mesh = _ambient_mesh()
-    if mesh is None:
+    The mesh is the ambient one (``jax.set_mesh``, read by
+    :func:`repro.distributed.constrain.current_mesh`): a packed update
+    traced without it skips every constraint here, so sharded entry
+    points trace inside that context.
+    """
+    if current_mesh() is None:
         return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, PartitionSpec(*([None] * x.ndim))))
+    return jax.lax.with_sharding_constraint(x, PartitionSpec())
 
 
 def constrain_rows(layout: PackedLayout, buf: jnp.ndarray) -> jnp.ndarray:
@@ -296,18 +282,15 @@ def constrain_rows(layout: PackedLayout, buf: jnp.ndarray) -> jnp.ndarray:
     No-op without an ambient mesh, so ZeRO layouts still run (padded
     but unsharded) on a single device — what the parity tests exploit.
     """
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    mesh = _ambient_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return buf
     if layout.shards > 1 and "data" in mesh.axis_names \
             and layout.total_rows % mesh.shape["data"] == 0:
-        spec = PartitionSpec("data", *([None] * (buf.ndim - 1)))
+        spec = PartitionSpec("data")
     else:
-        spec = PartitionSpec(*([None] * buf.ndim))
-    return jax.lax.with_sharding_constraint(
-        buf, NamedSharding(mesh, spec))
+        spec = PartitionSpec()
+    return jax.lax.with_sharding_constraint(buf, spec)
 
 
 def gather_rows(layout: PackedLayout, buf: jnp.ndarray) -> jnp.ndarray:

@@ -13,17 +13,21 @@ import math
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from jax._src import mesh as _mesh_lib
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 
-def current_mesh() -> Optional[Mesh]:
-    m = _mesh_lib.thread_resources.env.physical_mesh
+def current_mesh() -> Optional[AbstractMesh]:
+    """The ambient mesh, or None.
+
+    Entry points that trace a mesh-aware step do so inside
+    ``jax.set_mesh(mesh)``; the constraints below (and the packed
+    substrate's, see ``packing.constrain_rows``) see the mesh only
+    there, and are identity without it."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 
-def _batch_axes(mesh: Mesh, n: int):
+def _batch_axes(mesh: AbstractMesh, n: int):
     """(pod, data) prefix that divides n, else None."""
     ba = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     size = math.prod(mesh.shape[a] for a in ba) if ba else 1
@@ -40,8 +44,7 @@ def shard_batch(x, *, last: Optional[str] = None):
     if last is not None and last in mesh.axis_names \
             and x.shape[-1] % mesh.shape[last] == 0:
         spec[-1] = last
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*spec)))
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def shard_spec(x, *axes):
@@ -59,5 +62,4 @@ def shard_spec(x, *axes):
         names = tuple(n for n in names if n in mesh.axis_names)
         size = math.prod(mesh.shape[n] for n in names) if names else 1
         spec.append(names if names and dim % size == 0 else None)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*spec)))
+    return jax.lax.with_sharding_constraint(x, P(*spec))

@@ -13,7 +13,7 @@ from __future__ import annotations
 from typing import Any, Callable
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 
 def _count_in_jaxpr(jaxpr, name: str) -> int:

@@ -17,10 +17,15 @@ Responsibilities:
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import packing
+from repro.distributed.constrain import current_mesh
 from repro.kernels import lars_kernels, flash_decode as fd
 
 LANE = packing.LANE      # packed lane dim (multiple of 128)
@@ -49,6 +54,22 @@ def resolve_use_pallas(mode: bool | str) -> bool:
     return bool(mode)
 
 
+def _per_device(kernel, *args):
+    """Run ``kernel`` on replicated operands under the ambient mesh.
+
+    Mosaic kernels have no partitioning rule, so a multi-device mesh
+    cannot hand them to GSPMD. The packed substrate pins the replicated
+    layout's superbuffers replicated (``packing.constrain_rows``), so each
+    device runs the kernel on its own full copy inside ``shard_map`` —
+    the replicated data-parallel update, kept on the megakernels.
+    """
+    mesh = current_mesh()
+    if mesh is None or math.prod(mesh.shape.values()) == 1:
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
 # ------------------------------------------------------------ packed kernels
 
 def lars_norms_packed(layout: packing.PackedLayout, wbuf: jnp.ndarray,
@@ -59,8 +80,10 @@ def lars_norms_packed(layout: packing.PackedLayout, wbuf: jnp.ndarray,
     ONE Pallas launch (per-block partial sums) + a static segment fold.
     Returns two (num_slices,) f32 vectors.
     """
-    wsq_blk, gsq_blk = lars_kernels.norms_flat(
-        wbuf, gbuf, block_rows=layout.block_rows, interpret=_interpret())
+    wsq_blk, gsq_blk = _per_device(
+        functools.partial(lars_kernels.norms_flat,
+                          block_rows=layout.block_rows,
+                          interpret=_interpret()), wbuf, gbuf)
     ids = packing.block_slice_ids(layout)
     wsq = jax.ops.segment_sum(wsq_blk, ids, num_segments=layout.num_slices,
                               indices_are_sorted=True)
@@ -79,10 +102,12 @@ def lars_apply_packed(layout: packing.PackedLayout, wbuf: jnp.ndarray,
     """
     lr_blocks = packing.blocks_expand(layout,
                                       lr_slices.astype(jnp.float32))
-    return lars_kernels.apply_flat(
-        wbuf, gbuf, mbuf, lr_blocks, momentum=momentum,
-        weight_decay=weight_decay, block_rows=layout.block_rows,
-        interpret=_interpret())
+    return _per_device(
+        functools.partial(lars_kernels.apply_flat, momentum=momentum,
+                          weight_decay=weight_decay,
+                          block_rows=layout.block_rows,
+                          interpret=_interpret()),
+        wbuf, gbuf, mbuf, lr_blocks)
 
 
 def lars_apply_packed_q8(layout: packing.PackedLayout, wbuf: jnp.ndarray,
@@ -95,10 +120,12 @@ def lars_apply_packed_q8(layout: packing.PackedLayout, wbuf: jnp.ndarray,
     Returns (w_new, q_new, scale_new)."""
     lr_blocks = packing.blocks_expand(layout,
                                       lr_slices.astype(jnp.float32))
-    return lars_kernels.apply_flat_q8(
-        wbuf, gbuf, q_m, m_scale, lr_blocks, momentum=momentum,
-        weight_decay=weight_decay, block_rows=layout.block_rows,
-        interpret=_interpret())
+    return _per_device(
+        functools.partial(lars_kernels.apply_flat_q8, momentum=momentum,
+                          weight_decay=weight_decay,
+                          block_rows=layout.block_rows,
+                          interpret=_interpret()),
+        wbuf, gbuf, q_m, m_scale, lr_blocks)
 
 
 # ----------------------------------------------------- per-leaf adapters
@@ -163,7 +190,6 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     q4 = q.reshape(B, Hkv, G, D)
-    out4 = fd.flash_decode_grouped(q4, k, v,
-                                   lengths.reshape(B, 1).astype(jnp.int32),
+    out4 = fd.flash_decode_grouped(q4, k, v, lengths.astype(jnp.int32),
                                    scale=scale, bs=bs, interpret=interpret)
     return out4.reshape(B, H, D)

@@ -61,7 +61,7 @@ def _compile_step(cfg, shape, mesh, optimizer: str = "lars"):
     pspecs = param_pspecs(cfg, p_shapes, mesh)
 
     t0 = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.mode == "train":
             opt = get_optimizer(optimizer, learning_rate=0.01)
             state_shapes = jax.eval_shape(

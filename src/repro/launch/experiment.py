@@ -41,9 +41,11 @@ import jax
 from repro.experiments import (GRIDS, GridRunner, PopulationController,
                                format_table, get_grid, write_pbt_report,
                                write_report)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--grid", choices=sorted(GRIDS),
                     help="named grid from the registry")

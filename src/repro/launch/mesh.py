@@ -1,13 +1,33 @@
-"""Production mesh definition (TPU v5e target).
+"""Mesh construction: every mesh in the repo is built here.
 
-A FUNCTION, not a module-level constant, so importing this module never
+FUNCTIONS, not module-level constants, so importing this module never
 touches jax device state (smoke tests must see 1 CPU device; only
 dryrun.py sets the 512-host-device XLA flag).
+
+All axes are ``AxisType.Auto``: the step functions place data with
+``in_shardings``/``with_sharding_constraint`` and leave propagation to
+GSPMD, which is what ``jax.make_mesh``'s Explicit default would refuse.
 """
 
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with Auto axis types over ``devices`` (default:
+    the leading ``prod(shape)`` local devices)."""
+    n = 1
+    for s in shape:
+        n *= s
+    devs = list(devices) if devices is not None else jax.devices()[:n]
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,18 +43,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    devs = jax.devices()
-    if len(devs) < n:
+    if len(jax.devices()) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {dict(zip(axes, shape))}, have "
-            f"{len(devs)} — run under dryrun.py (XLA host-device flag)")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+            f"{len(jax.devices())} — run under dryrun.py (XLA host-device "
+            f"flag)")
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
     """Degenerate mesh over the local device(s) — examples / smoke runs."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_from_spec(spec: str, *, allow_none: bool = False):
@@ -47,17 +67,16 @@ def mesh_from_spec(spec: str, *, allow_none: bool = False):
     """
     if allow_none and spec == "none":
         return None
-    devs = jax.devices()
+    ndev = len(jax.devices())
     if spec == "auto":
-        return jax.make_mesh((len(devs), 1), ("data", "model"))
+        return make_mesh((ndev, 1), ("data", "model"))
     try:
         data, model = (int(s) for s in spec.lower().split("x"))
     except ValueError:
         choices = "'auto'" + (", 'none'" if allow_none else "")
         raise SystemExit(f"--mesh expects {choices} or DATAxMODEL, "
                          f"got {spec!r}")
-    if data * model > len(devs):
+    if data * model > ndev:
         raise SystemExit(f"--mesh {spec} needs {data * model} devices, "
-                         f"have {len(devs)}")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devs[:data * model])
+                         f"have {ndev}")
+    return make_mesh((data, model), ("data", "model"))

@@ -40,6 +40,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_spec
 from repro.launch.overrides import apply_overrides
 from repro.models import build_model
@@ -177,6 +178,7 @@ def run_library_scenario(engine: ServeEngine, cfg, args) -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--reduced", action="store_true",

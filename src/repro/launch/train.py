@@ -15,7 +15,9 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Iterator, Optional
 
 import jax
 import numpy as np
@@ -27,6 +29,7 @@ from repro.core.scaling import scaled_lr
 from repro.data import (ShardedLoader, TokenTaskConfig, batch_iterator,
                         synthetic_mnist, token_batches)
 from repro.distributed.sharding import batch_pspecs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import mesh_from_spec
 from repro.launch.overrides import apply_overrides
 from repro.models import build_model
@@ -59,7 +62,7 @@ def make_lr_schedule(args) -> schedules.Schedule:
         scaled_lr(args.lr, args.base_batch, args.batch, args.lr_policy))
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--reduced", action="store_true",
@@ -99,38 +102,43 @@ def main() -> None:
     ap.add_argument("--set", action="append", default=[],
                     metavar="FIELD=VALUE",
                     help="config override, e.g. --set remat_block=8")
-    args = ap.parse_args()
-
+    args = ap.parse_args(argv)
     if args.batch % args.accum_steps:
         raise SystemExit(f"--batch {args.batch} must be divisible by "
                          f"--accum-steps {args.accum_steps}")
+    return args
 
+
+@dataclasses.dataclass
+class TrainJob:
+    """Everything a training run is built from, before any step runs."""
+
+    cfg: Any
+    model: Any
+    mesh: Any
+    pipeline: TrainPipeline
+    batch: int                 # global batch
+    host_batches: Iterator     # global numpy batches, generated from --seed
+    eval_batches: Optional[list]
+
+
+def build_job(args, *, use_pallas: bool | str = "auto") -> TrainJob:
+    """Config, model, mesh, optimizer, pipeline and host data for
+    ``args``. ``use_pallas`` forces LARS's optimizer engine ("auto": the
+    megakernels on TPU, the jnp engine elsewhere)."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     cfg = apply_overrides(cfg, args.set)
     model = build_model(cfg)
     mesh = mesh_from_spec(args.mesh)
-
+    engine = {} if use_pallas == "auto" else {"use_pallas": use_pallas}
     opt = get_optimizer(args.optimizer, learning_rate=make_lr_schedule(args),
-                        slot_dtype=args.opt_state_dtype)
+                        slot_dtype=args.opt_state_dtype, **engine)
     pipeline = TrainPipeline(model, opt, cfg,
                              accum_steps=args.accum_steps,
                              precision=args.precision, mesh=mesh)
-    state = pipeline.init_state(jax.random.key(args.seed))
-    if args.resume:
-        state = pipeline.place_state(
-            restore_train_state(args.resume, state))
-        print(f"resumed from {args.resume} "
-              f"at step {int(state.opt_state.step)}")
-    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
-    micro = args.batch // args.accum_steps
-    print(f"arch={cfg.name} family={cfg.family} params={n_params:,} "
-          f"opt={opt.name} lr={args.lr} mesh={dict(mesh.shape)} "
-          f"global_batch={args.batch} micro_batch={micro} "
-          f"accum={args.accum_steps} precision={args.precision}")
-
-    bspecs = batch_pspecs(cfg, mesh, batch=args.batch)
+    eval_batches = None
     if cfg.family == "cnn":
         # size the procedural dataset to the global batch —
         # batch_iterator's epoch wrap can only cover a shortfall of one
@@ -143,17 +151,46 @@ def main() -> None:
                         for i in range(0, len(x_te), 256)]
     else:
         host_batches = lm_batches(cfg, args.batch, args.seq, args.seed)
-        eval_batches = None
-    batches = ShardedLoader(host_batches, mesh, bspecs)
+    return TrainJob(cfg, model, mesh, pipeline, args.batch, host_batches,
+                    eval_batches)
 
+
+def run_job(job: TrainJob, state, steps: int, *, log_every: int = 10):
+    """Train ``steps`` steps through the double-buffered loader.
+    Returns (state, history, wall seconds)."""
+    bspecs = batch_pspecs(job.cfg, job.mesh, batch=job.batch)
+    batches = ShardedLoader(job.host_batches, job.mesh, bspecs)
+    eval_fn = make_eval_step(job.model, job.cfg) if job.eval_batches \
+        else None
     t0 = time.perf_counter()
-    state, hist = train_loop(pipeline, state, batches, args.steps,
-                             log_every=args.log_every,
-                             eval_fn=make_eval_step(model, cfg)
-                             if eval_batches else None,
-                             eval_batches=eval_batches)
-    dt = time.perf_counter() - t0
-    batches.close()
+    try:
+        state, hist = train_loop(job.pipeline, state, batches, steps,
+                                 log_every=log_every, eval_fn=eval_fn,
+                                 eval_batches=job.eval_batches)
+    finally:
+        batches.close()
+    return state, hist, time.perf_counter() - t0
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    args = parse_args(argv)
+    job = build_job(args)
+    state = job.pipeline.init_state(jax.random.key(args.seed))
+    if args.resume:
+        state = job.pipeline.place_state(
+            restore_train_state(args.resume, state))
+        print(f"resumed from {args.resume} "
+              f"at step {int(state.opt_state.step)}")
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
+    micro = args.batch // args.accum_steps
+    print(f"arch={job.cfg.name} family={job.cfg.family} params={n_params:,} "
+          f"opt={job.pipeline.optimizer.name} lr={args.lr} "
+          f"mesh={dict(job.mesh.shape)} global_batch={args.batch} "
+          f"micro_batch={micro} accum={args.accum_steps} "
+          f"precision={args.precision}")
+    state, hist, dt = run_job(job, state, args.steps,
+                              log_every=args.log_every)
     print(f"done: {args.steps} steps in {dt:.1f}s "
           f"({args.steps / dt:.2f} steps/s, "
           f"{args.steps * args.batch / dt:.0f} examples/s)")

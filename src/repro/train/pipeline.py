@@ -24,7 +24,7 @@ through gradient accumulation + LR scaling/warmup + mixed precision.
   in/out shardings from :mod:`repro.distributed.sharding` and
   ``donate_argnums=(0,)`` so the TrainState is updated in place
   (params + slots never double-buffer). Tracing happens under
-  ``with mesh:`` — required by the packed substrate's sharding
+  ``jax.set_mesh(mesh)`` — required by the packed substrate's sharding
   constraints (see ``packing.constrain_rows``).
 * **ZeRO optimizer-state sharding** — ``zero=True`` (requires a mesh
   with a ``data`` axis) row-shards every packed optimizer slot across
@@ -100,6 +100,21 @@ def cast_floats(tree: Pytree, dtype) -> Pytree:
     return tree_map(
         lambda x: x.astype(dtype)
         if jnp.issubdtype(x.dtype, jnp.floating) else x, tree)
+
+
+def compiled_peak_bytes(compiled) -> Optional[int]:
+    """temp + argument + output bytes of a compiled program.
+
+    ``None`` only where the backend has no memory analysis; on a TPU a
+    missing analysis is an error, not a blank column.
+    """
+    mem = compiled.memory_analysis()
+    if mem is None:
+        if jax.default_backend() == "tpu":
+            raise RuntimeError("TPU compile returned no memory analysis")
+        return None
+    return int(mem.temp_size_in_bytes + mem.argument_size_in_bytes
+               + mem.output_size_in_bytes)
 
 
 # -------------------------------------------------------------- pipeline
@@ -338,7 +353,7 @@ class TrainPipeline:
         def call(s, b):
             # trace/execute under the ambient mesh: the packed substrate
             # pins its superbuffers replicated only when it can see one
-            with mesh:
+            with jax.set_mesh(mesh):
                 return fn(s, b)
 
         return call
@@ -346,21 +361,15 @@ class TrainPipeline:
     def compiled_peak_bytes(self, batch) -> Optional[int]:
         """Compiled peak memory (temp + args + outputs) of this step on
         an example batch, cached per pipeline; ``None`` on backends
-        without memory analysis. Family-agnostic — any batch pytree the
-        step accepts works, so the experiment harness reports the same
-        column for CNN and token-LM cells."""
-        if getattr(self, "_peak_bytes", "miss") != "miss":
-            return self._peak_bytes
-        peak = None
-        try:
+        without memory analysis (see :func:`compiled_peak_bytes`).
+        Family-agnostic — any batch pytree the step accepts works, so
+        the experiment harness reports the same column for CNN and
+        token-LM cells."""
+        if getattr(self, "_peak_bytes", "miss") == "miss":
             state = self.init_state(jax.random.key(0))
-            mem = self.lower(state, batch).compile().memory_analysis()
-            peak = int(mem.temp_size_in_bytes + mem.argument_size_in_bytes
-                       + mem.output_size_in_bytes)
-        except Exception:
-            pass
-        self._peak_bytes = peak
-        return peak
+            self._peak_bytes = compiled_peak_bytes(
+                self.lower(state, batch).compile())
+        return self._peak_bytes
 
     def lower(self, state: TrainState, batch):
         """``jax.stages.Lowered`` for this step — compile-time
@@ -368,7 +377,7 @@ class TrainPipeline:
         peak-memory deltas reported by ``benchmarks/paper_sweep.py``)."""
         fn = self._jit(state, batch)
         if self.mesh is not None:
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 return fn.lower(state, batch)
         return fn.lower(state, batch)
 

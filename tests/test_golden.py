@@ -33,6 +33,7 @@ from repro.configs import get_config
 from repro.core import adamw, grad_stats, lamb, lars, sgd
 from repro.data import (TokenTaskConfig, batch_iterator, synthetic_mnist,
                         token_batches)
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train import TrainPipeline
 
@@ -267,7 +268,7 @@ def _check_main() -> int:
         except AssertionError as e:
             failures.append(f"{family}/{opt_name}/b{batch}: {e}")
     if len(jax.devices()) >= 8:
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         got = run_trajectory("cnn", "lars", 128, mesh=mesh)
         try:
             _compare(got, _load_golden("cnn", "lars", 128),

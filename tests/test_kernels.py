@@ -18,6 +18,7 @@ from repro.kernels import ops, ref
     ((4, 256, 512), True),      # stacked, aligned
     ((1, 100), True),           # stacked with L=1
     ((4096, 512), False),       # big unstacked
+    ((33, 4096), False),        # 264 rows: a last grid tile of 8 rows
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lars_norms_matches_ref(shape, stacked, dtype):
@@ -41,6 +42,7 @@ def test_lars_norms_matches_ref(shape, stacked, dtype):
     ((5, 7), False),
     ((3, 33, 17), True),
     ((2, 128, 512), True),
+    ((33, 4096), False),        # 264 rows: a last grid tile of 8 rows
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lars_apply_matches_ref(shape, stacked, dtype):

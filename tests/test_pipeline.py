@@ -20,6 +20,7 @@ from repro.checkpoint import restore_train_state, save_train_state
 from repro.configs import get_config
 from repro.core import lars, packing, schedules
 from repro.data import Prefetcher, ShardedLoader
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train import (TrainPipeline, create_train_state, make_train_step,
                          train_loop)
@@ -265,7 +266,7 @@ def test_pipeline_equivalence_on_eight_devices():
 
 def _subproc_main():
     assert len(jax.devices()) == 8
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("qwen3-14b").reduced()
     model = build_model(cfg)
     opt = lars(0.05, trust_coefficient=0.01)
@@ -361,7 +362,7 @@ def test_prefetcher_close_unblocks_infinite_source():
 
 def test_sharded_loader_prefetch_places_on_device():
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def batches():
         for i in range(3):

@@ -313,6 +313,28 @@ def test_int8_pallas_path_is_two_launches_and_matches_jnp():
                                    rtol=2e-5, atol=1e-8)
 
 
+def test_int8_apply_kernel_matches_dequant_apply_requant_across_tiles():
+    """The fused int8 apply over a buffer of more than one grid tile
+    (264 rows: a full 256-row tile, then an 8-row one) equals
+    dequantize -> f32 momentum update -> requantize, group by group:
+    identical codes, scales and weights."""
+    from repro.kernels import lars_kernels
+    rows, lane = 264, packing.LANE
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    w, g, m = (jax.random.normal(k, (rows, lane)) for k in ks[:3])
+    lr = jax.random.uniform(ks[3], (rows // packing.BLOCK_ROWS, 1))
+    layout = packing.build_layout({"x": w}, {"x": False})
+    q, scale = packing.quantize_q8(layout, m)
+    w_new, q_new, s_new = lars_kernels.apply_flat_q8(
+        w, g, q, scale, lr, momentum=0.9, weight_decay=1e-4)
+    m_new = 0.9 * packing.dequantize_q8(layout, q, scale) + jnp.repeat(
+        lr, packing.BLOCK_ROWS, axis=0) * (g + 1e-4 * w)
+    q_ref, s_ref = packing.quantize_q8(layout, m_new)
+    np.testing.assert_array_equal(np.asarray(q_new), np.asarray(q_ref))
+    np.testing.assert_allclose(s_new, s_ref, rtol=1e-6)
+    np.testing.assert_allclose(w_new, w - m_new, rtol=1e-6, atol=1e-6)
+
+
 # -------------------------------------------------- checkpoint substrate
 
 def test_int8_slots_roundtrip_npz_byte_identical(tmp_path):

@@ -18,6 +18,7 @@ import pytest
 from repro.configs import get_config
 from repro.kernels import flash_decode as fd
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve import (DecodeEngine, QueueFull, SamplerConfig, ServeEngine,
                          parse_sampler, sample)
@@ -333,7 +334,7 @@ def _subproc_main():
         prompts = _prompts(cfg, [5, 9, 7, 6, 11, 5], seed=13)
         ref = ServeEngine(model, params, cfg, slots=4, capacity=64
                           ).generate(prompts, 5)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         eng = ServeEngine(model, params, cfg, slots=4, capacity=64,
                           mesh=mesh)
         out = eng.generate(prompts, 5)

@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed import param_pspecs, cache_pspecs
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import param_shapes
 from repro.models import build_model
 
@@ -69,7 +70,7 @@ def test_divisibility_guard_drops_axis():
     """whisper vocab 51865 is not divisible by 16 -> replicated."""
     cfg = get_config("whisper-base")
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
+    mesh = make_mesh((1, 1), ("data", "model"),
                          devices=jax.devices()[:1])
     # fake a 16-way mesh via explicit shape map
     import repro.distributed.sharding as SH
@@ -157,10 +158,10 @@ def _subproc_main():
     # single device reference
     ref_state, ref_metrics = jax.jit(step)(state, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     sspecs = state_pspecs(cfg, jax.eval_shape(lambda: state), mesh)
     bspecs = batch_pspecs(cfg, mesh, batch=8)
-    with mesh:
+    with jax.set_mesh(mesh):
         dist = jax.jit(step,
                        in_shardings=(tree_named(mesh, sspecs),
                                      tree_named(mesh, bspecs)),

@@ -1,0 +1,140 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret-mode tests (``test_kernels.py``) pin what the kernels compute;
+they cannot see what Mosaic refuses — block shapes off the (8, 128)
+tiling, broadcasts it cannot lower, VMEM overruns. Here each kernel is
+compiled for one chip of a described (not attached) ``v5e:2x2``
+topology at smollm-135m's widths: its whole-model optimizer superbuffer
+and its decode-attention shapes. Nothing runs; a refusal fails the test.
+
+The topology is described inside a module fixture (never at import):
+only one process may load the TPU library, and every test worker
+imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core import packing
+from repro.kernels import flash_decode as fd
+from repro.kernels import lars_kernels as LK
+from repro.models import build_model
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-chip compile can be written to the persistent cache
+    # but never read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def smollm_buffer():
+    """(rows, lane) and group count of smollm-135m's packed superbuffer."""
+    model = build_model(get_config("smollm-135m"))
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    layout = packing.build_layout(shapes, model.stacked_marker(shapes))
+    return layout.buffer_shape, layout.num_blocks
+
+
+def _compile_for_chip(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "kernel missing from the program"
+
+
+def test_norms_flat_compiles_for_v5e(one_chip, smollm_buffer):
+    shape, _ = smollm_buffer
+    buf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    _compile_for_chip(
+        lambda w, g: LK.norms_flat(w, g, interpret=False), buf, buf)
+
+
+def test_apply_flat_compiles_for_v5e(one_chip, smollm_buffer):
+    shape, groups = smollm_buffer
+    buf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    col = jax.ShapeDtypeStruct((groups, 1), jnp.float32, sharding=one_chip)
+    _compile_for_chip(
+        lambda lr, w, g, m: LK.apply_flat(
+            w, g, m, lr, momentum=0.9, weight_decay=1e-4, interpret=False),
+        col, buf, buf, buf)
+
+
+def test_apply_flat_q8_compiles_for_v5e(one_chip, smollm_buffer):
+    shape, groups = smollm_buffer
+    buf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    codes = jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip)
+    col = jax.ShapeDtypeStruct((groups, 1), jnp.float32, sharding=one_chip)
+    _compile_for_chip(
+        lambda lr, sc, w, g, q: LK.apply_flat_q8(
+            w, g, q, sc, lr, momentum=0.9, weight_decay=1e-4,
+            interpret=False),
+        col, col, buf, buf, codes)
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen3-14b"])
+def test_flash_decode_compiles_for_v5e(one_chip, arch):
+    H, Hkv, D = get_config(arch).attn_dims
+    B, S = 8, 2048
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _compile_for_chip(
+        lambda q, k, v, n: fd.flash_decode_grouped(
+            q, k, v, n, scale=D ** -0.5, interpret=False),
+        spec((B, Hkv, H // Hkv, D), jnp.bfloat16),
+        spec((B, S, Hkv, D), jnp.bfloat16),
+        spec((B, S, Hkv, D), jnp.bfloat16),
+        spec((B,), jnp.int32))
+
+
+def test_packed_lars_update_compiles_on_four_chip_mesh(topo, monkeypatch):
+    """Replicated data parallelism keeps the megakernels: under a (4, 1)
+    mesh each chip runs them on its own copy (Mosaic kernels cannot be
+    partitioned by GSPMD)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import lars
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)  # Mosaic, not CPU
+    mesh = make_mesh((4, 1), ("data", "model"), devices=topo.devices)
+    opt = lars(0.1, use_pallas=True)
+    params = {"w": jnp.zeros((256, 512)), "stk": jnp.zeros((3, 64, 128)),
+              "b": jnp.zeros((512,))}
+    stacked = {"w": False, "stk": True, "b": False}
+    state = jax.eval_shape(lambda p: opt.init(p, stacked=stacked), params)
+    rep = NamedSharding(mesh, P())
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep)
+
+    args = jax.tree_util.tree_map(spec, (params, state, params))
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(lambda g, s, p: opt.update(g, s, p)).lower(*args)
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 2

@@ -36,6 +36,7 @@ import test_golden  # noqa: E402  (RUNS, tolerances, run_trajectory)
 
 from repro.configs import get_config                     # noqa: E402
 from repro.core import lars, packing                     # noqa: E402
+from repro.launch.mesh import make_mesh                  # noqa: E402
 from repro.models import build_model                     # noqa: E402
 from repro.train import TrainPipeline, TrainState        # noqa: E402
 
@@ -191,7 +192,7 @@ def test_fuse_update_true_valid_on_pure_data_mesh():
     """The old gate rejected explicit fuse_update=True under ANY mesh;
     it is now valid whenever the mesh is pure data-parallel."""
     from repro.data import synthetic_mnist
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     cfg = get_config("lenet-mnist")
     pipe = TrainPipeline(build_model(cfg), lars(0.05, momentum=0.9), cfg,
                          accum_steps=2, mesh=mesh, fuse_update=True,
@@ -260,7 +261,7 @@ def _fused_zero_check(mesh) -> None:
 
 def _check_main() -> int:
     assert len(jax.devices()) >= 8, "needs 8 forced host devices"
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     failures = []
     for family, opt_name, batch in test_golden.RUNS:
         got = test_golden.run_trajectory(family, opt_name, batch,
@@ -280,7 +281,7 @@ def _check_main() -> int:
     except AssertionError as e:
         failures.append(f"fused zero: {e}")
     # model-parallel mesh still rejects the explicit fuse
-    mp_mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mp_mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("lenet-mnist")
     pipe = TrainPipeline(build_model(cfg), lars(0.05, momentum=0.9), cfg,
                          accum_steps=2, mesh=mp_mesh, fuse_update=True,
