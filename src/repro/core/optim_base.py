@@ -51,6 +51,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.core import packing
 from repro.core import trust_ratio as tr
 
@@ -259,15 +260,17 @@ def _packed_update(rule: LayerwiseRule, layout: packing.PackedLayout, lr,
     else:
         wbuf = packing.pack(layout, params)
     packed_grads = isinstance(grads, PackedGrads)
-    gbuf = grads.buf if packed_grads else packing.pack(layout, grads)
-    if rule.needs_grad_sq:
-        # square in f32 (pack would cast AFTER the square, and a bf16
-        # square then diverges from the tree engine's f32 one). Squaring
-        # the packed buffer is elementwise-identical (0^2 == 0 in the
-        # padding), so the fused path needs no second pack.
-        ctx = dict(ctx, grad_sq=jnp.square(gbuf) if packed_grads
-                   else packing.pack(layout, tree_map(
-                       lambda g: jnp.square(g.astype(jnp.float32)), grads)))
+    with jax.named_scope(phases.GRAD_PACK):
+        gbuf = grads.buf if packed_grads else packing.pack(layout, grads)
+        if rule.needs_grad_sq:
+            # square in f32 (pack would cast AFTER the square, and a bf16
+            # square then diverges from the tree engine's f32 one).
+            # Squaring the packed buffer is elementwise-identical (0^2 ==
+            # 0 in the padding), so the fused path needs no second pack.
+            ctx = dict(ctx, grad_sq=jnp.square(gbuf) if packed_grads
+                       else packing.pack(layout, tree_map(
+                           lambda g: jnp.square(g.astype(jnp.float32)),
+                           grads)))
     quant = slot_dtype == "int8"
     q8_kernel = quant and use_pallas and rule.packed_apply_q8 is not None
     if quant:
@@ -282,36 +285,40 @@ def _packed_update(rule: LayerwiseRule, layout: packing.PackedLayout, lr,
     u, f32_slots = rule.direction(ctx, gbuf, wbuf, f32_slots)
     ratio = None
     if rule.trust is not None:
-        if use_pallas and rule.packed_norms is not None:
-            w_norm, u_norm = rule.packed_norms(layout, wbuf, u)
-        elif rule.trust_operand_is_grad:
-            w_norm = jnp.sqrt(packing.slice_sumsq(layout, wbuf))
-            # fused path: ||sum_i g_i|| must be taken on the ACCUMULATED
-            # buffer (cross terms make it impossible to accumulate from
-            # per-microbatch norms); the tree path keeps the per-leaf
-            # reductions that fuse with the gradient pack
-            u_norm = jnp.sqrt(packing.slice_sumsq(layout, gbuf)) \
-                if packed_grads \
-                else jnp.sqrt(packing.tree_slice_sumsq(layout, grads))
+        with jax.named_scope(phases.LARS_NORMS):
+            if use_pallas and rule.packed_norms is not None:
+                w_norm, u_norm = rule.packed_norms(layout, wbuf, u)
+            elif rule.trust_operand_is_grad:
+                w_norm = jnp.sqrt(packing.slice_sumsq(layout, wbuf))
+                # fused path: ||sum_i g_i|| must be taken on the
+                # ACCUMULATED buffer (cross terms make it impossible to
+                # accumulate from per-microbatch norms); the tree path
+                # keeps the per-leaf reductions that fuse with the
+                # gradient pack
+                u_norm = jnp.sqrt(packing.slice_sumsq(layout, gbuf)) \
+                    if packed_grads \
+                    else jnp.sqrt(packing.tree_slice_sumsq(layout, grads))
+            else:
+                w_norm, u_norm = packing.slice_norms(layout, wbuf, u)
+        with jax.named_scope(phases.TRUST_RATIO):
+            ratio = rule.trust(ctx, w_norm, u_norm)
+            if rule.skip_adaptation_1d:
+                ratio = jnp.where(packing.adapt_mask(layout), ratio, 1.0)
+    with jax.named_scope(phases.LARS_APPLY):
+        if use_pallas and (q8_kernel or rule.packed_apply is not None):
+            ones = jnp.ones((layout.num_slices,), jnp.float32)
+            lr_slices = lr * (ratio if ratio is not None else ones)
+            if q8_kernel:
+                wbuf2, new_slots = rule.packed_apply_q8(
+                    ctx, layout, wbuf, gbuf, u, lr_slices, slots)
+            else:
+                wbuf2, new_slots = rule.packed_apply(
+                    ctx, layout, wbuf, gbuf, u, lr_slices, f32_slots)
         else:
-            w_norm, u_norm = packing.slice_norms(layout, wbuf, u)
-        ratio = rule.trust(ctx, w_norm, u_norm)
-        if rule.skip_adaptation_1d:
-            ratio = jnp.where(packing.adapt_mask(layout), ratio, 1.0)
-    if use_pallas and (q8_kernel or rule.packed_apply is not None):
-        ones = jnp.ones((layout.num_slices,), jnp.float32)
-        lr_slices = lr * (ratio if ratio is not None else ones)
-        if q8_kernel:
-            wbuf2, new_slots = rule.packed_apply_q8(
-                ctx, layout, wbuf, gbuf, u, lr_slices, slots)
-        else:
-            wbuf2, new_slots = rule.packed_apply(
-                ctx, layout, wbuf, gbuf, u, lr_slices, f32_slots)
-    else:
-        local_lr = lr if ratio is None \
-            else lr * packing.rows_expand(layout, ratio)
-        wbuf2, new_slots = rule.apply(ctx, wbuf, gbuf, u, local_lr,
-                                      f32_slots)
+            local_lr = lr if ratio is None \
+                else lr * packing.rows_expand(layout, ratio)
+            wbuf2, new_slots = rule.apply(ctx, wbuf, gbuf, u, local_lr,
+                                          f32_slots)
     if quant and not q8_kernel:
         # quantize-on-write: each updated rule slot back to codes+scales
         for k in rule.slots:
@@ -330,7 +337,8 @@ def _packed_update(rule: LayerwiseRule, layout: packing.PackedLayout, lr,
         # slot (including the master / persistent weight buffer) stays
         # row-sharded across steps
         wbuf2 = packing.gather_rows(layout, wbuf2)
-    new_params = packing.unpack(layout, wbuf2)
+    with jax.named_scope(phases.PARAM_UNPACK):
+        new_params = packing.unpack(layout, wbuf2)
     return new_params, new_slots
 
 

@@ -137,6 +137,7 @@ def flash_decode_grouped(q4: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(lengths, q_bd, k.reshape(B, S, C), v.reshape(B, S, C))
     # keep each query head's own kv head's lanes: the block diagonal
     out = out.reshape(B, Hkv, G, Hkv, D)

@@ -101,6 +101,7 @@ def norms_flat(w2: jnp.ndarray, g2: jnp.ndarray, *, block_rows: int = 8,
                    jax.ShapeDtypeStruct((ngrp, 1), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="lars_norms",
     )(w2, g2)
     return wsq[:, 0], gsq[:, 0]
 
@@ -151,6 +152,7 @@ def apply_flat(w2: jnp.ndarray, g2: jnp.ndarray, m2: jnp.ndarray,
                    jax.ShapeDtypeStruct((R, C), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="lars_apply",
     )(lr_blocks, w2, g2, m2)
     return w_new, m_new
 
@@ -229,5 +231,6 @@ def apply_flat_q8(w2: jnp.ndarray, g2: jnp.ndarray, q2: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((tile, C), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="lars_apply_q8",
     )(lr_blocks, scale, w2, g2, q2)
     return w_new, q_new, s_new

@@ -23,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.models import layers as L
 
 NEG_INF = -1.0e30
@@ -212,10 +213,12 @@ def attention_block(cfg, p, x, positions, *, causal=True, prefix_len=None,
     H, Hkv, hd = cfg.attn_dims
     q, k, v = qkv_project(cfg, p, x, positions)
     w = cfg.sliding_window if window is None else window
-    out = attention_core(q, k, v, q_positions=positions, causal=causal,
-                         window=w, prefix_len=prefix_len,
-                         softcap=cfg.attn_logit_softcap,
-                         q_chunk=cfg.attn_q_chunk, flash_vjp=cfg.flash_vjp)
+    with jax.named_scope(phases.ATTENTION_CORE):
+        out = attention_core(q, k, v, q_positions=positions, causal=causal,
+                             window=w, prefix_len=prefix_len,
+                             softcap=cfg.attn_logit_softcap,
+                             q_chunk=cfg.attn_q_chunk,
+                             flash_vjp=cfg.flash_vjp)
     return out.reshape(B, S, H * hd) @ p["wo"]
 
 

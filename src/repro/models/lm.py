@@ -26,6 +26,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.models import layers as L
 from repro.models import attention as A
 from repro.models import mla as MLA
@@ -154,20 +155,22 @@ class LanguageModel:
                                  with_attn, lambda x: x, x)
             return x, aux
 
-        h = L.apply_norm(cfg, x, params_l["ln1"])
-        if cfg.use_mla:
-            attn_out = MLA.mla_block(cfg, params_l["attn"], h, positions)
-        else:
-            attn_out = A.attention_block(cfg, params_l["attn"], h, positions,
-                                         prefix_len=prefix_len)
-        x = x + attn_out
-        h = L.apply_norm(cfg, x, params_l["ln2"])
-        if cfg.family == "moe":
-            y, moe_aux = moe_block(cfg, params_l["moe"], h)
-            aux = aux + moe_aux["aux_loss"]
-            x = x + y
-        else:
-            x = x + mlp_block(cfg, params_l["mlp"], h)
+        with jax.named_scope(phases.ATTENTION):
+            h = L.apply_norm(cfg, x, params_l["ln1"])
+            if cfg.use_mla:
+                attn_out = MLA.mla_block(cfg, params_l["attn"], h, positions)
+            else:
+                attn_out = A.attention_block(cfg, params_l["attn"], h,
+                                             positions, prefix_len=prefix_len)
+            x = x + attn_out
+        with jax.named_scope(phases.MLP):
+            h = L.apply_norm(cfg, x, params_l["ln2"])
+            if cfg.family == "moe":
+                y, moe_aux = moe_block(cfg, params_l["moe"], h)
+                aux = aux + moe_aux["aux_loss"]
+                x = x + y
+            else:
+                x = x + mlp_block(cfg, params_l["mlp"], h)
         return x, aux
 
     def forward(self, params, tokens, *, image_embeddings=None,
@@ -181,7 +184,8 @@ class LanguageModel:
         path computes the vocab matmul itself).
         """
         cfg = self.cfg
-        x = self.embed_tokens(params, tokens)
+        with jax.named_scope(phases.EMBED):
+            x = self.embed_tokens(params, tokens)
         prefix_len = None
         if cfg.family == "vlm":
             assert image_embeddings is not None, "vlm needs image stub"
@@ -239,10 +243,11 @@ class LanguageModel:
                                                   params["layers"])
                 carry, _ = body(carry, (params_l, jnp.asarray(i)))
         x, aux = carry
-        if return_hidden:
-            return L.apply_norm(cfg, x, params["final_norm"]), \
-                {"aux_loss": aux}
-        return self.logits(params, x), {"aux_loss": aux}
+        with jax.named_scope(phases.HEAD_LOSS):
+            if return_hidden:
+                return L.apply_norm(cfg, x, params["final_norm"]), \
+                    {"aux_loss": aux}
+            return self.logits(params, x), {"aux_loss": aux}
 
     def unembed_matrix(self, params):
         return (params["embed"].T if self.cfg.tie_embeddings
@@ -423,7 +428,8 @@ class LanguageModel:
         kv_len = pos+1). Not supported for prefix-LM (vlm) prefill.
         """
         cfg = self.cfg
-        x = self.embed_tokens(params, tokens)
+        with jax.named_scope(phases.EMBED):
+            x = self.embed_tokens(params, tokens)
         prefix_len = None
         if cfg.family == "vlm":
             assert lengths is None, "vlm prefill has no lengths support"

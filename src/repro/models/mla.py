@@ -17,6 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.models import layers as L
 from repro.models.attention import attention_core, _insert_at, _insert_span
 
@@ -85,9 +86,11 @@ def mla_block(cfg, p, x, positions) -> jnp.ndarray:
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], axis=-1)
 
-    out = attention_core(q, k, v, q_positions=positions, causal=True,
-                         scale=(nope + rope) ** -0.5,
-                         q_chunk=cfg.attn_q_chunk, flash_vjp=cfg.flash_vjp)
+    with jax.named_scope(phases.ATTENTION_CORE):
+        out = attention_core(q, k, v, q_positions=positions, causal=True,
+                             scale=(nope + rope) ** -0.5,
+                             q_chunk=cfg.attn_q_chunk,
+                             flash_vjp=cfg.flash_vjp)
     return out.reshape(B, S, H * vd) @ p["wo"]
 
 

@@ -51,6 +51,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.core import packing
 from repro.core.optim_base import PackedGrads
 from repro.train.state import TrainState
@@ -196,6 +197,7 @@ class TrainPipeline:
                          if packed and marker_fn is not None else None)
         self._compiled: Optional[Callable] = None
         self._step_fn = self._build_step()
+        self._calls = 0     # steps dispatched: the profiler's step number
 
     # ------------------------------------------------------------- state
 
@@ -287,7 +289,8 @@ class TrainPipeline:
                         # f32 addition chain as the tree carry below —
                         # the accumulated buffer is bit-identical to
                         # pack(tree-accumulated grads)
-                        gsum = gsum + packing.pack(layout, g)
+                        with jax.named_scope(phases.GRAD_PACK):
+                            gsum = gsum + packing.pack(layout, g)
                     else:
                         gsum = tree_map(
                             lambda a, gi: a + gi.astype(jnp.float32),
@@ -308,8 +311,11 @@ class TrainPipeline:
                 # gradient, so the (single) LARS trust ratio matches a
                 # one-shot step on the whole global batch.
                 inv = 1.0 / k
-                grads = PackedGrads(gsum * inv) if fuse \
-                    else tree_map(lambda g: g * inv, gsum)
+                if fuse:
+                    with jax.named_scope(phases.GRAD_PACK):
+                        grads = PackedGrads(gsum * inv)
+                else:
+                    grads = tree_map(lambda g: g * inv, gsum)
                 loss, aux_loss = lsum * inv, asum * inv
 
             new_params, new_opt = optimizer.update(
@@ -390,4 +396,9 @@ class TrainPipeline:
                     f"accum_steps={self.accum_steps}")
         if self._compiled is None:
             self._compiled = self._compile(state, batch)
-        return self._compiled(state, batch)
+        # one host span per optimizer step, the step marker of profiler
+        # step-time views; a few microseconds when no trace is running
+        with jax.profiler.StepTraceAnnotation("train_step",
+                                              step_num=self._calls):
+            self._calls += 1
+            return self._compiled(state, batch)

@@ -26,6 +26,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.train.losses import (chunked_lm_loss, classification_loss,
                                 lm_loss)
 from repro.train.metrics import accuracy
@@ -65,11 +66,13 @@ def _forward_and_loss(model, cfg, params, batch):
     if getattr(cfg, "loss_chunk", 0):
         hidden, aux = model.forward(params, batch["tokens"],
                                     return_hidden=True)
-        loss = chunked_lm_loss(hidden, model.unembed_matrix(params),
-                               batch["tokens"], chunk=cfg.loss_chunk)
+        with jax.named_scope(phases.HEAD_LOSS):
+            loss = chunked_lm_loss(hidden, model.unembed_matrix(params),
+                                   batch["tokens"], chunk=cfg.loss_chunk)
         return loss + aux.get("aux_loss", 0.0), (None, aux)
     logits, aux = model.forward(params, batch["tokens"])
-    loss = lm_loss(logits, batch["tokens"])
+    with jax.named_scope(phases.HEAD_LOSS):
+        loss = lm_loss(logits, batch["tokens"])
     return loss + aux.get("aux_loss", 0.0), (logits, aux)
 
 

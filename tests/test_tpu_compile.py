@@ -6,6 +6,8 @@ tiling, broadcasts it cannot lower, VMEM overruns. Here each kernel is
 compiled for one chip of a described (not attached) ``v5e:2x2``
 topology at smollm-135m's widths: its whole-model optimizer superbuffer
 and its decode-attention shapes. Nothing runs; a refusal fails the test.
+Each kernel must keep its own name in the compiled program, the name a
+device trace shows it by.
 
 The topology is described inside a module fixture (never at import):
 only one process may load the TPU library, and every test worker
@@ -13,6 +15,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,16 +63,19 @@ def smollm_buffer():
     return layout.buffer_shape, layout.num_blocks
 
 
-def _compile_for_chip(fn, *args):
+def _compile_for_chip(fn, *args, kernel):
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text, "kernel missing from the program"
+    assert re.search(rf"^\s*(ROOT )?%{kernel}\.\d+ = .*custom_call_target="
+                     r'"tpu_custom_call"', text, re.M), \
+        f"kernel {kernel} missing from the program"
 
 
 def test_norms_flat_compiles_for_v5e(one_chip, smollm_buffer):
     shape, _ = smollm_buffer
     buf = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     _compile_for_chip(
-        lambda w, g: LK.norms_flat(w, g, interpret=False), buf, buf)
+        lambda w, g: LK.norms_flat(w, g, interpret=False), buf, buf,
+        kernel="lars_norms")
 
 
 def test_apply_flat_compiles_for_v5e(one_chip, smollm_buffer):
@@ -79,7 +85,7 @@ def test_apply_flat_compiles_for_v5e(one_chip, smollm_buffer):
     _compile_for_chip(
         lambda lr, w, g, m: LK.apply_flat(
             w, g, m, lr, momentum=0.9, weight_decay=1e-4, interpret=False),
-        col, buf, buf, buf)
+        col, buf, buf, buf, kernel="lars_apply")
 
 
 def test_apply_flat_q8_compiles_for_v5e(one_chip, smollm_buffer):
@@ -91,7 +97,7 @@ def test_apply_flat_q8_compiles_for_v5e(one_chip, smollm_buffer):
         lambda lr, sc, w, g, q: LK.apply_flat_q8(
             w, g, q, sc, lr, momentum=0.9, weight_decay=1e-4,
             interpret=False),
-        col, col, buf, buf, codes)
+        col, col, buf, buf, codes, kernel="lars_apply_q8")
 
 
 @pytest.mark.parametrize("arch", ["smollm-135m", "qwen3-14b"])
@@ -108,7 +114,7 @@ def test_flash_decode_compiles_for_v5e(one_chip, arch):
         spec((B, Hkv, H // Hkv, D), jnp.bfloat16),
         spec((B, S, Hkv, D), jnp.bfloat16),
         spec((B, S, Hkv, D), jnp.bfloat16),
-        spec((B,), jnp.int32))
+        spec((B,), jnp.int32), kernel="flash_decode")
 
 
 def test_packed_lars_update_compiles_on_four_chip_mesh(topo, monkeypatch):
