@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import phases
+from repro.kernels import causal_attention as CA
 from repro.models import layers as L
 
 NEG_INF = -1.0e30
@@ -100,8 +101,15 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
                    flash_vjp: bool = False) -> jnp.ndarray:
     """Online-softmax attention, scanned over KV chunks.
 
-    q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D). q_positions: (Sq,) or (B, Sq).
+    q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D). q_positions: (Sq,) or
+    (B, Sq); None means 0..Sq-1, a fact known while tracing.
     Returns (B, Sq, H, D) in q.dtype; accumulation in f32.
+
+    Plain causal self-attention over positions 0..S-1 (no window,
+    prefix, length, KV positions or softcap) runs in the Pallas flash
+    kernels on a TPU wherever :func:`repro.kernels.causal_attention.
+    supports` the shapes; that path ignores ``kv_chunk``, ``q_chunk``
+    and ``flash_vjp``, which shape only this jnp path, its oracle.
 
     ``q_chunk`` > 0 additionally scans over query blocks (flash-style 2-D
     tiling): bounds the live (Sq_blk, kv_chunk) score tile — required at
@@ -113,6 +121,14 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
     flash-decoding split-KV combine, compiler-inserted).
     """
     B, Sq, H, D = q.shape
+    if scale is None:
+        scale = D ** -0.5
+    if q_positions is None:
+        if (causal and not window and prefix_len is None and kv_len is None
+                and kv_positions is None and not softcap
+                and CA.supports(q, k, v, scale)):
+            return CA.causal_attention(q, k, v, scale=scale)
+        q_positions = jnp.arange(Sq)
     if q_chunk and Sq > q_chunk and Sq % q_chunk == 0:
         nq = Sq // q_chunk
         if q_positions.ndim == 1:
@@ -136,15 +152,11 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
             and not isinstance(prefix_len, jnp.ndarray) \
             and kv_positions is None:
         from repro.models import flash_attn as FA
-        cfgt = (causal, window, prefix_len,
-                scale if scale is not None else q.shape[-1] ** -0.5,
-                softcap, kv_len)
+        cfgt = (causal, window, prefix_len, scale, softcap, kv_len)
         return FA.flash_attention(q, k, v, q_positions, cfgt, kv_chunk)
     Sk, Hkv = k.shape[1], k.shape[2]
     Dv = v.shape[3]                       # may differ from D (MLA)
     G = H // Hkv
-    if scale is None:
-        scale = D ** -0.5
     kc = min(kv_chunk, Sk)
     if Sk % kc:
         pad = kc - Sk % kc
@@ -208,10 +220,12 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
 
 def attention_block(cfg, p, x, positions, *, causal=True, prefix_len=None,
                     window=None) -> jnp.ndarray:
-    """Full attention sub-block for train/prefill (projections included)."""
+    """Full attention sub-block for train/prefill (projections included).
+    ``positions`` None means 0..S-1 (see :func:`attention_core`)."""
     B, S, d = x.shape
     H, Hkv, hd = cfg.attn_dims
-    q, k, v = qkv_project(cfg, p, x, positions)
+    q, k, v = qkv_project(cfg, p, x,
+                          jnp.arange(S) if positions is None else positions)
     w = cfg.sliding_window if window is None else window
     with jax.named_scope(phases.ATTENTION_CORE):
         out = attention_core(q, k, v, q_positions=positions, causal=causal,

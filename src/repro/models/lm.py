@@ -158,7 +158,9 @@ class LanguageModel:
         with jax.named_scope(phases.ATTENTION):
             h = L.apply_norm(cfg, x, params_l["ln1"])
             if cfg.use_mla:
-                attn_out = MLA.mla_block(cfg, params_l["attn"], h, positions)
+                attn_out = MLA.mla_block(
+                    cfg, params_l["attn"], h,
+                    jnp.arange(h.shape[1]) if positions is None else positions)
             else:
                 attn_out = A.attention_block(cfg, params_l["attn"], h,
                                              positions, prefix_len=prefix_len)
@@ -193,7 +195,7 @@ class LanguageModel:
                 [image_embeddings.astype(x.dtype), x], axis=1)
             prefix_len = image_embeddings.shape[1]
         B, S, d = x.shape
-        positions = jnp.arange(S)
+        positions = None    # 0..S-1, known while tracing (attention_core)
         shared = params.get("shared")
 
         def body(carry, inp):
@@ -456,7 +458,7 @@ class LanguageModel:
             else:
                 q, k, v = A.qkv_project(cfg, params_l["attn"], h, positions)
                 out = A.attention_core(
-                    q, k, v, q_positions=positions, causal=True,
+                    q, k, v, q_positions=None, causal=True,
                     window=cfg.sliding_window, prefix_len=prefix_len,
                     softcap=cfg.attn_logit_softcap,
                     q_chunk=cfg.attn_q_chunk, flash_vjp=cfg.flash_vjp)
@@ -773,7 +775,7 @@ class LanguageModel:
                     h = L.apply_norm(cfg, x, shared["ln1"])
                     q, k, v = A.qkv_project(cfg, shared["attn"], h, positions)
                     out = A.attention_core(
-                        q, k, v, q_positions=positions, causal=True,
+                        q, k, v, q_positions=None, causal=True,
                         window=cfg.sliding_window,
                         q_chunk=cfg.attn_q_chunk, flash_vjp=cfg.flash_vjp)
                     x = x + out.reshape(B, S, H * hd) @ shared["attn"]["wo"]

@@ -1,0 +1,130 @@
+"""The causal flash-attention kernels (``kernels/causal_attention``)
+against ``attention_core``'s jnp online softmax, and the dispatch
+between them.
+
+The kernels run in interpret mode here. Outputs and gradients with
+respect to q, k and v are compared by the norm of their difference over
+the norm of the jnp path's, within twice bf16's epsilon: both paths
+take bf16 operands and return bf16, so each rounds its outputs once.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import AbstractMesh
+
+from repro.kernels import causal_attention as CA
+from repro.models.attention import attention_core
+
+TOL = 2 * float(jnp.finfo(jnp.bfloat16).eps)
+
+
+def qkv(B, S, H, Hkv, D, Dv=None, Sk=None, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    Sk = Sk or S
+    return (jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16),
+            jax.random.normal(ks[1], (B, Sk, Hkv, D), jnp.bfloat16),
+            jax.random.normal(ks[2], (B, Sk, Hkv, Dv or D), jnp.bfloat16))
+
+
+def rel(a, b):
+    a, b = (jnp.asarray(x, jnp.float32) for x in (a, b))
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("S,D", [(256, 64), (512, 64), (1024, 64),
+                                 (256, 128)])
+def test_kernel_matches_jnp_path(S, D):
+    B, H, Hkv = 2, 6, 2
+    q, k, v = qkv(B, S, H, Hkv, D)
+    w = jax.random.normal(jax.random.key(1), (B, S, H, D), jnp.float32)
+    scale = D ** -0.5
+
+    def kernel(q, k, v):
+        return CA.causal_attention(q, k, v, scale=scale, interpret=True)
+
+    def jnp_path(q, k, v):
+        return attention_core(q, k, v, q_positions=jnp.arange(S))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w)
+
+    out, ref = jax.jit(kernel)(q, k, v), jax.jit(jnp_path)(q, k, v)
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    assert rel(out, ref) < TOL
+    grads = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(q, k, v)
+    refs = jax.jit(jax.grad(loss(jnp_path), argnums=(0, 1, 2)))(q, k, v)
+    for g, r in zip(grads, refs):
+        assert g.shape == r.shape and rel(g, r) < TOL
+
+
+@pytest.fixture
+def spy(monkeypatch):
+    """Count the calls that take the kernel, on a backend said to be
+    the TPU (the kernels themselves still interpret on this CPU)."""
+    calls = []
+    kernel = CA.causal_attention
+
+    def counted(*args, **kwargs):
+        calls.append(1)
+        return kernel(*args, **kwargs)
+
+    monkeypatch.setattr(CA, "on_tpu", lambda: True)
+    monkeypatch.setattr(CA, "causal_attention", counted)
+    return calls
+
+
+def took_kernel(calls, q, k, v, **kwargs):
+    kwargs.setdefault("q_positions", None)
+    jax.eval_shape(lambda q, k, v: attention_core(q, k, v, **kwargs),
+                   q, k, v)
+    return bool(calls)
+
+
+def test_eligible_input_takes_the_kernel_on_tpu(spy):
+    assert took_kernel(spy, *qkv(2, 512, 6, 2, 64))
+    assert took_kernel(spy, *qkv(2, 512, 6, 2, 64), q_chunk=128,
+                       flash_vjp=True)
+
+
+def test_eligible_input_keeps_the_jnp_path_off_tpu(monkeypatch):
+    calls = []
+    monkeypatch.setattr(CA, "causal_attention",
+                        lambda *a, **kw: calls.append(1))
+    assert not CA.on_tpu()
+    assert not took_kernel(calls, *qkv(2, 512, 6, 2, 64))
+
+
+B, S, H, HKV, D = 2, 512, 6, 2, 64
+# (shape changes, attention_core arguments) of each ineligible call
+INELIGIBLE = {
+    "window": ({}, dict(window=128)),
+    "prefix_len": ({}, dict(prefix_len=64)),
+    "kv_len": ({}, dict(kv_len=jnp.full((B,), S))),
+    "kv_positions": ({}, dict(kv_positions=jnp.arange(S))),
+    "softcap": ({}, dict(softcap=30.0)),
+    "positions_given": ({}, dict(q_positions=jnp.arange(S))),
+    "sq_ne_sk": (dict(Sk=2 * S), {}),
+    "dv_ne_d": (dict(Dv=32), {}),
+    "decode": (dict(S=1, Sk=S), dict(kv_len=jnp.full((B,), S))),
+    "not_a_block": (dict(S=S + 64), {}),
+    "inexact_scale": ({}, dict(scale=D ** -0.5 * 0.9)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(INELIGIBLE))
+def test_ineligible_input_keeps_the_jnp_path(spy, case):
+    shape, kwargs = INELIGIBLE[case]
+    q, k, v = qkv(**{**dict(B=B, S=S, H=H, Hkv=HKV, D=D), **shape})
+    assert not took_kernel(spy, q, k, v, **kwargs)
+
+
+def test_f32_operands_keep_the_jnp_path(spy):
+    q, k, v = (x.astype(jnp.float32) for x in qkv(B, S, H, HKV, D))
+    assert not took_kernel(spy, q, k, v)
+
+
+def test_multi_device_mesh_keeps_the_jnp_path(spy):
+    with jax.sharding.use_abstract_mesh(
+            AbstractMesh((2, 1), ("data", "model"))):
+        assert not took_kernel(spy, *qkv(B, S, H, HKV, D))

@@ -4,8 +4,9 @@ Interpret-mode tests (``test_kernels.py``) pin what the kernels compute;
 they cannot see what Mosaic refuses — block shapes off the (8, 128)
 tiling, broadcasts it cannot lower, VMEM overruns. Here each kernel is
 compiled for one chip of a described (not attached) ``v5e:2x2``
-topology at smollm-135m's widths: its whole-model optimizer superbuffer
-and its decode-attention shapes. Nothing runs; a refusal fails the test.
+topology at smollm-135m's widths: its whole-model optimizer superbuffer,
+its training-attention shapes and its decode-attention shapes. Nothing
+runs; a refusal fails the test.
 Each kernel must keep its own name in the compiled program, the name a
 device trace shows it by.
 
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core import packing
+from repro.kernels import causal_attention as CA
 from repro.kernels import flash_decode as fd
 from repro.kernels import lars_kernels as LK
 from repro.models import build_model
@@ -65,9 +67,10 @@ def smollm_buffer():
 
 def _compile_for_chip(fn, *args, kernel):
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert re.search(rf"^\s*(ROOT )?%{kernel}\.\d+ = .*custom_call_target="
-                     r'"tpu_custom_call"', text, re.M), \
-        f"kernel {kernel} missing from the program"
+    for name in (kernel,) if isinstance(kernel, str) else kernel:
+        assert re.search(rf"^\s*(ROOT )?%{name}\.\d+ = .*custom_call_target="
+                         r'"tpu_custom_call"', text, re.M), \
+            f"kernel {name} missing from the program"
 
 
 def test_norms_flat_compiles_for_v5e(one_chip, smollm_buffer):
@@ -115,6 +118,31 @@ def test_flash_decode_compiles_for_v5e(one_chip, arch):
         spec((B, S, Hkv, D), jnp.bfloat16),
         spec((B, S, Hkv, D), jnp.bfloat16),
         spec((B,), jnp.int32), kernel="flash_decode")
+
+
+@pytest.mark.parametrize("pass_,kernels", [
+    pytest.param("forward", ["splash_mqa_fwd_no_residuals"], id="forward"),
+    pytest.param("backward", ["splash_mqa_fwd_residuals",
+                              "splash_mqa_dkv_no_residuals"], id="backward")])
+def test_causal_attention_compiles_for_v5e(one_chip, pass_, kernels):
+    """smollm-135m's training attention: 8 x 2048 tokens, 9 query heads
+    on 3 KV heads of 64, bf16."""
+    H, Hkv, D = get_config("smollm-135m").attn_dims
+    B, S = 8, 2048
+
+    def attn(q, k, v):
+        return CA.causal_attention(q, k, v, scale=D ** -0.5,
+                                   interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32))
+
+    fn = attn if pass_ == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    _compile_for_chip(
+        fn, jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16,
+                                 sharding=one_chip),
+        *[jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
+                               sharding=one_chip)] * 2, kernel=kernels)
 
 
 def test_packed_lars_update_compiles_on_four_chip_mesh(topo, monkeypatch):
