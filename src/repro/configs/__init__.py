@@ -7,14 +7,16 @@ from repro.configs.shapes import (SHAPES, TRAIN_4K, PREFILL_32K,  # noqa: F401
                                   DECODE_32K, LONG_500K,
                                   LONG_CONTEXT_WINDOW)
 
-from repro.configs import (whisper_base, deepseek_v2_236b, zamba2_7b,
+from repro.configs import (whisper_base, deepseek_v2_236b,
+                           deepseek_v2_lite, zamba2_7b,
                            smollm_135m, minitron_8b, falcon_mamba_7b,
                            qwen3_14b, qwen2_72b, paligemma_3b,
                            granite_moe_3b_a800m, lenet_mnist)
 
 ARCHS: dict[str, ModelConfig] = {
     m.CONFIG.name: m.CONFIG
-    for m in (whisper_base, deepseek_v2_236b, zamba2_7b, smollm_135m,
+    for m in (whisper_base, deepseek_v2_236b, deepseek_v2_lite, zamba2_7b,
+              smollm_135m,
               minitron_8b, falcon_mamba_7b, qwen3_14b, qwen2_72b,
               paligemma_3b, granite_moe_3b_a800m, lenet_mnist)
 }

@@ -13,6 +13,24 @@ from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (Peng et al., arXiv:2309.00071) as DeepSeek-V2
+    publishes it under ``rope_scaling``: rotary frequencies blended
+    between the original and the ``factor``-stretched ones over the
+    band that ``beta_fast`` .. ``beta_slow`` rotations mark within
+    ``original_max_position_embeddings``; cos/sin scaled by
+    m(mscale) / m(mscale_all_dim) and the softmax by m(mscale_all_dim)^2,
+    where m(s) = 0.1 s ln(factor) + 1."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                    # dense | moe | ssm | hybrid | encdec | vlm | cnn
@@ -29,6 +47,7 @@ class ModelConfig:
     qk_norm: bool = False          # qwen3
     use_rope: bool = True          # whisper: sinusoidal only
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRN] = None
     sliding_window: int = 0        # 0 = full attention; >0 = window size
     attn_logit_softcap: float = 0.0
 
@@ -37,11 +56,15 @@ class ModelConfig:
     num_shared_experts: int = 0    # deepseek-v2
     experts_per_token: int = 0
     moe_d_ff: int = 0              # per-expert hidden dim
-    capacity_factor: float = 1.25
-    router_aux_coef: float = 0.01
-    moe_groups: int = 1            # dispatch groups (shard-local routing);
-                                   # dry-run sets = data shards so capacity
-                                   # buffers stay per-shard-local
+    router_aux_coef: float = 0.01  # balance-loss weight (DeepSeek's alpha)
+    seq_aux: bool = False          # DeepSeek's per-sequence balance loss
+                                   # (else Switch's, over the whole batch)
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum 1
+    routed_scaling_factor: float = 1.0
+    experts_held: int = 0          # routed experts this layer holds (0: all)
+    expert_offset: int = 0         # id of the first expert held
+    first_k_dense: int = 0         # leading dense layers (MLP of d_ff)
+                                   # ahead of the MoE layers (deepseek)
 
     # --- MLA (deepseek-v2) ---
     use_mla: bool = False
@@ -145,7 +168,10 @@ class ModelConfig:
                 experts_per_token=min(self.experts_per_token,
                                       min(self.num_experts, max_experts)),
                 num_shared_experts=min(self.num_shared_experts, 1),
-                moe_d_ff=min(self.moe_d_ff, 2 * d_model))
+                moe_d_ff=min(self.moe_d_ff, 2 * d_model),
+                experts_held=0, expert_offset=0,
+                first_k_dense=min(self.first_k_dense,
+                                  changes["num_layers"] - 1))
         if self.use_mla:
             changes.update(kv_lora_rank=min(self.kv_lora_rank, 64),
                            q_lora_rank=0,
@@ -221,8 +247,12 @@ def param_count(cfg: ModelConfig) -> tuple[int, int]:
         expert = mlp_params(cfg.moe_d_ff)
         shared = cfg.num_shared_experts * expert
         router = d * cfg.num_experts
-        total += L * (attn + router + shared + cfg.num_experts * expert)
-        active += L * (attn + router + shared + cfg.experts_per_token * expert)
+        dense = cfg.first_k_dense * (attn + mlp_params(cfg.d_ff))
+        Lm = L - cfg.first_k_dense
+        held = cfg.experts_held or cfg.num_experts
+        total += dense + Lm * (attn + router + shared + held * expert)
+        active += dense + Lm * (attn + router + shared
+                                + cfg.experts_per_token * expert)
     elif cfg.family == "ssm":
         per = ssm_params()
         total += L * per

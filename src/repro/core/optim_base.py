@@ -8,10 +8,11 @@ Design notes
   ``Optimizer.update(grads, state, params, stacked=None) ->
   (new_params, new_state)``. The update is a single jit-able function of
   pytrees; the step counter lives in the state so LR schedules are pure.
-* ``stacked``: a pytree of bools mirroring ``params``. ``True`` marks a
-  parameter whose leading axis stacks layers for ``lax.scan`` (shape
-  ``(L, ...)``). Layer-wise optimizers (LARS/LAMB) compute their trust
-  ratios *per leading index* for such tensors, otherwise the "layer-wise"
+* ``stacked``: a pytree mirroring ``params`` of counts of leading slice
+  axes. ``True`` (1) marks a parameter whose leading axis stacks layers
+  for ``lax.scan`` (shape ``(L, ...)``); 2 marks routed experts stacked
+  ``(L, E, ...)``. Layer-wise optimizers (LARS/LAMB) compute their trust
+  ratios *per slice* for such tensors, otherwise the "layer-wise"
   semantics of the paper silently degrade to "whole-stack-wise".
 
 The LayerwiseRule abstraction
@@ -187,7 +188,7 @@ def _tree_update(rule: LayerwiseRule, lr, ctx: dict, grads: Pytree,
     """
     n_rule = len(rule.slots)
 
-    def leaf(g, w, s: bool, *extra):
+    def leaf(g, w, s: int, *extra):
         sl = dict(zip(rule.slots, extra[:n_rule]))
         gf = g.astype(jnp.float32)
         wf = extra[n_rule] if master is not None else w.astype(jnp.float32)
@@ -518,12 +519,13 @@ def zeros_like_tree(params: Pytree, dtype=jnp.float32) -> Pytree:
 
 
 def normalize_stacked(params: Pytree, stacked: Optional[Pytree]) -> Pytree:
-    """Return a full bool pytree mirroring params (default: all False)."""
+    """Return a full pytree of slice-axis counts mirroring params
+    (default: all 0; ``True`` counts as 1)."""
     if stacked is None:
-        return jax.tree_util.tree_map(lambda _: False, params)
-    # Broadcast a prefix tree of bools over params.
+        return jax.tree_util.tree_map(lambda _: 0, params)
+    # Broadcast a prefix tree of markers over params.
     return jax.tree_util.tree_map(
-        lambda s, p: bool(s), stacked, params)
+        lambda s, p: int(s), stacked, params)
 
 
 def apply_updates(params: Pytree, updates: Pytree) -> Pytree:

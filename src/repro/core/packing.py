@@ -11,9 +11,11 @@ leaf*. This module removes the per-leaf axis entirely:
   (row offset, layer count, rows per layer slice, original shape/dtype)
   describing how every leaf maps into one ``(total_rows, lane)`` f32
   superbuffer. "Layer slice" follows the paper's layer-wise semantics:
-  an unstacked leaf is one slice; a leaf marked ``stacked`` (shape
-  ``(L, ...)``, scanned over layers) contributes ``L`` independent slices
-  so each layer keeps its own trust ratio.
+  an unstacked leaf is one slice; a leaf whose marker is ``n`` > 0 stacks
+  slices on its first ``n`` axes (``(L, ...)`` scanned over layers: 1;
+  ``(L, E, ...)`` routed experts: 2) and contributes one independent
+  slice per index of those axes, so each layer, or each expert of each
+  layer, keeps its own trust ratio.
 * ``pack`` / ``unpack`` move a pytree into / out of the superbuffer
   (flatten, zero-pad each slice to a whole number of ``block_rows`` row
   blocks, concatenate along rows). Zero padding is norm-neutral.
@@ -86,8 +88,8 @@ class Segment:
     name: str                   # "/"-joined key path (debug / telemetry)
     shape: tuple[int, ...]      # original leaf shape
     dtype: str                  # original leaf dtype name
-    stacked: bool               # leading axis is a layer stack
-    layers: int                 # number of layer slices (1 if unstacked)
+    stacked: int                # leading axes that stack slices (0: none)
+    layers: int                 # number of slices (1 if unstacked)
     rows: int                   # padded rows per slice (multiple of BLOCK_ROWS)
     n: int                      # true elements per slice (before padding)
     row_offset: int             # first superbuffer row of slice 0
@@ -131,19 +133,19 @@ class PackedLayout:
         """Rows holding real data (the shards=1 layout's total_rows)."""
         return self.total_rows - self.pad_rows
 
-    def stacked_flags(self) -> tuple[bool, ...]:
+    def stacked_flags(self) -> tuple[int, ...]:
         return tuple(s.stacked for s in self.segments)
 
 
-def _slice_rank(shape: tuple[int, ...], stacked: bool) -> int:
-    return len(shape) - (1 if stacked else 0)
+def _slice_rank(shape: tuple[int, ...], stacked: int) -> int:
+    return len(shape) - int(stacked)
 
 
 @functools.lru_cache(maxsize=64)
 def _build_layout_static(treedef, names: tuple[str, ...],
                          shapes: tuple[tuple[int, ...], ...],
                          dtypes: tuple[str, ...],
-                         stacked: tuple[bool, ...],
+                         stacked: tuple[int, ...],
                          lane: int, block_rows: int,
                          shards: int) -> PackedLayout:
     segments = []
@@ -152,9 +154,10 @@ def _build_layout_static(treedef, names: tuple[str, ...],
     per_block = lane * block_rows
     for name, shape, dtype, stk in zip(names, shapes, dtypes, stacked):
         size = int(np.prod(shape)) if shape else 1
-        if stk and not shape:
-            raise ValueError(f"scalar leaf {name!r} cannot be stacked")
-        layers = shape[0] if stk else 1
+        if stk > len(shape):
+            raise ValueError(f"leaf {name!r} of shape {shape} cannot stack "
+                             f"slices on {stk} axes")
+        layers = math.prod(shape[:stk])
         if layers == 0:
             raise ValueError(f"empty layer stack for leaf {name!r}")
         n = size // layers
@@ -184,7 +187,8 @@ def build_layout(params: Pytree, stacked: Pytree, *, lane: int = LANE,
                  block_rows: int = BLOCK_ROWS,
                  shards: int = 1) -> PackedLayout:
     """Static layout from a param pytree (arrays or ShapeDtypeStructs)
-    and a full bool pytree marking (L, ...) layer-stacked leaves.
+    and a full pytree giving each leaf's count of leading slice axes
+    (``True`` counts as 1: an (L, ...) layer stack).
 
     ``shards``: ZeRO row-shard count — rows are padded so the buffer
     splits evenly across that many shards (see :class:`PackedLayout`).
@@ -198,7 +202,7 @@ def build_layout(params: Pytree, stacked: Pytree, *, lane: int = LANE,
     names = tuple(path_str(path) for path, _ in leaves)
     shapes = tuple(tuple(leaf.shape) for _, leaf in leaves)
     dtypes = tuple(jnp.dtype(leaf.dtype).name for _, leaf in leaves)
-    flags = tuple(bool(s) for s in stk_leaves)
+    flags = tuple(int(s) for s in stk_leaves)
     return _build_layout_static(treedef, names, shapes, dtypes, flags,
                                 lane, block_rows, int(shards))
 
@@ -508,7 +512,7 @@ def blocks_expand(layout: PackedLayout, per_slice: jnp.ndarray
 def check_marker(layout: PackedLayout, params: Pytree,
                  stacked: Pytree) -> None:
     """Validate an update-time stacked marker against the init-time layout."""
-    flags = tuple(bool(s) for s in layout.treedef.flatten_up_to(stacked))
+    flags = tuple(int(s) for s in layout.treedef.flatten_up_to(stacked))
     if flags != layout.stacked_flags():
         raise ValueError(
             "stacked marker passed to update() disagrees with the marker "

@@ -6,8 +6,9 @@ Paper Eq. (2)/(3):
 
 with eta the trust coefficient and beta the weight decay. "Layer" in the
 paper means each weight tensor of the DML script; here it means each
-parameter leaf — and each *leading-axis slice* of a leaf marked ``stacked``
-(layer-scanned models store params as ``(L, ...)``).
+parameter leaf — and each *leading-axes slice* of a leaf marked
+``stacked`` (layer-scanned models store params as ``(L, ...)``, marker 1;
+routed experts as ``(L, E, ...)``, marker 2: one slice per expert).
 
 Conventions (following You et al. ICPP'18 and common practice):
 * parameters whose effective rank is <= 1 (biases, norm scales, scalar
@@ -24,24 +25,24 @@ from typing import Optional
 import jax.numpy as jnp
 
 
-def reduction_axes(x: jnp.ndarray, stacked: bool) -> Optional[tuple[int, ...]]:
+def reduction_axes(x: jnp.ndarray, stacked: int) -> Optional[tuple[int, ...]]:
     """Axes over which a 'per-layer' norm reduces.
 
     Non-stacked: all axes (one scalar norm per tensor).
-    Stacked: all but axis 0 (one norm per layer slice).
+    Stacked on n axes (``True`` is 1): all but the first n (one norm per
+    slice).
     """
-    if stacked:
-        return tuple(range(1, x.ndim))
-    return tuple(range(x.ndim))
+    return tuple(range(int(stacked), x.ndim))
 
 
-def effective_rank(x: jnp.ndarray, stacked: bool) -> int:
-    return x.ndim - (1 if stacked else 0)
+def effective_rank(x: jnp.ndarray, stacked: int) -> int:
+    return x.ndim - int(stacked)
 
 
-def layer_norms(w: jnp.ndarray, g: jnp.ndarray, stacked: bool
+def layer_norms(w: jnp.ndarray, g: jnp.ndarray, stacked: int
                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(||w||, ||g||) per layer, computed in f32; shape () or (L,)."""
+    """(||w||, ||g||) per slice, computed in f32; shape () or the stacked
+    axes' (L,) or (L, E)."""
     axes = reduction_axes(w, stacked)
     wf = w.astype(jnp.float32)
     gf = g.astype(jnp.float32)
@@ -70,8 +71,9 @@ def lamb_trust_ratio(w_norm: jnp.ndarray, u_norm: jnp.ndarray, *,
 
 
 def broadcast_ratio(ratio: jnp.ndarray, like: jnp.ndarray,
-                    stacked: bool) -> jnp.ndarray:
-    """Reshape a () or (L,) ratio so it broadcasts against ``like``."""
-    if not stacked:
+                    stacked: int) -> jnp.ndarray:
+    """Reshape a per-slice ratio so it broadcasts against ``like``."""
+    n = int(stacked)
+    if not n:
         return ratio
-    return ratio.reshape((like.shape[0],) + (1,) * (like.ndim - 1))
+    return ratio.reshape(like.shape[:n] + (1,) * (like.ndim - n))

@@ -30,7 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Pytree = Any
 
-STACKED_KEYS = ("layers", "enc_layers", "dec_layers")
+STACKED_KEYS = ("layers", "dense_layers", "enc_layers", "dec_layers")
 
 # (module-context, leaf-name) -> spec for the trailing (own) dims.
 # "@model" / "@data" mark mesh axes; None = replicated dim.

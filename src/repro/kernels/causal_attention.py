@@ -20,7 +20,12 @@ kernels' ops ``splash_mqa_fwd_residuals`` (forward under autodiff),
 
 Splash takes no softmax scale; it is folded into q before the kernel.
 That is exact only where the scale is a power of two (head_dim 64:
-1/8), so :func:`supports` accepts no other scale.
+1/8), so :func:`supports` accepts no other scale. A caller whose scale
+is not one folds it into q itself while q is still f32, before q's one
+rounding to bf16, and passes scale 1: q is then rounded once, as it
+always was (MLA, whose YaRN scale is 192^-0.5 m^2, does so).
+
+K and V may differ in head dim (MLA: 192 for q and k, 128 for v).
 """
 
 from __future__ import annotations
@@ -55,17 +60,16 @@ def on_tpu() -> bool:
 def supports(q, k, v, scale: float) -> bool:
     """Whether the kernel computes this call exactly as the jnp path
     would, on this backend: causal self-attention over the same sequence
-    (Sq == Sk, a multiple of a block), one head dim for K and V, GQA
-    groups, bf16 operands, a scale that folds into bf16 q without
-    rounding, and one device (no partitioning rule for Mosaic kernels
-    under a mesh)."""
+    (Sq == Sk, a multiple of a block), GQA groups, bf16 operands, a
+    scale that folds into bf16 q without rounding, and one device (no
+    partitioning rule for Mosaic kernels under a mesh)."""
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     mesh = current_mesh()
     return (on_tpu()
             and (mesh is None or math.prod(mesh.shape.values()) == 1)
             and Sq == Sk and _largest(FWD_BLOCKS, Sq) > 0
-            and v.shape[3] == D and H % Hkv == 0
+            and H % Hkv == 0
             and q.dtype == k.dtype == v.dtype == jnp.bfloat16
             and math.frexp(scale)[0] == 0.5)
 
@@ -85,18 +89,19 @@ def _kernel(seq: int, groups: int, interpret: bool):
 
 def causal_attention(q, k, v, *, scale: float,
                      interpret: bool | None = None) -> jnp.ndarray:
-    """softmax(scale * q k^T, causal) v for q (B, S, H, D), k/v
-    (B, S, Hkv, D), S a multiple of a block; returns (B, S, H, D) in
-    q.dtype. ``interpret`` None takes the backend's mode."""
+    """softmax(scale * q k^T, causal) v for q (B, S, H, D), k
+    (B, S, Hkv, D), v (B, S, Hkv, Dv), S a multiple of a block; returns
+    (B, S, H, Dv) in q.dtype. ``interpret`` None takes the backend's
+    mode."""
     B, S, H, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[3]
     G = H // Hkv
     if interpret is None:
         interpret = default_interpret()
     qs = (q * jnp.asarray(scale, q.dtype)).reshape(B, S, Hkv, G, D)
     qg = qs.transpose(0, 2, 3, 1, 4).reshape(B * Hkv, G, S, D)
     kg = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, D)
-    vg = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, D)
+    vg = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dv)
     out = jax.vmap(_kernel(S, G, interpret))(qg, kg, vg)
-    return out.reshape(B, Hkv, G, S, D).transpose(0, 3, 1, 2, 4).reshape(
-        B, S, H, D)
+    return out.reshape(B, Hkv, G, S, Dv).transpose(0, 3, 1, 2, 4).reshape(
+        B, S, H, Dv)

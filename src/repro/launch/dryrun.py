@@ -177,7 +177,7 @@ def lower_pair(arch: str, shape_name: str, mesh, mesh_name: str,
     """
     import dataclasses as dc
     shape = get_shape(shape_name)
-    cfg = adapt_config(get_config(arch), shape, mesh)
+    cfg = adapt_config(get_config(arch), shape)
     if overrides:
         cfg = dc.replace(cfg, **overrides)
     chips = mesh.size

@@ -7,35 +7,23 @@ shape-dependent config carve-outs from DESIGN.md §5:
 
   * long_500k on attention-cache archs -> sliding window 8192 (the
     sub-quadratic variant; MLA is exempt — its compressed latent cache
-    fits at 524k natively, which is the point of MLA);
-  * MoE dispatch groups = batch shards, so capacity buffers stay
-    shard-local.
+    fits at 524k natively, which is the point of MLA).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
 
 from repro.configs import InputShape, LONG_CONTEXT_WINDOW
 
 SDS = jax.ShapeDtypeStruct
 
 
-def batch_shards(mesh: Mesh) -> int:
-    n = 1
-    for a in ("pod", "data"):
-        if a in mesh.axis_names:
-            n *= mesh.shape[a]
-    return n
-
-
-def adapt_config(cfg, shape: InputShape, mesh: Mesh):
+def adapt_config(cfg, shape: InputShape):
     changes: dict[str, Any] = {}
     if (shape.mode == "decode" and shape.seq_len > LONG_CONTEXT_WINDOW
             and not cfg.use_mla and not cfg.is_attention_free
@@ -47,10 +35,6 @@ def adapt_config(cfg, shape: InputShape, mesh: Mesh):
             and cfg.sliding_window == 0):
         # hybrid shared-attention KV window for long-context decode
         changes["sliding_window"] = LONG_CONTEXT_WINDOW
-    if cfg.num_experts:
-        tokens = shape.global_batch * (shape.seq_len
-                                       if shape.mode != "decode" else 1)
-        changes["moe_groups"] = math.gcd(tokens, batch_shards(mesh))
     if changes:
         return dataclasses.replace(cfg, **changes)
     return cfg

@@ -4,6 +4,8 @@ activations. Pure functions over plain dict params.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -67,19 +69,59 @@ def init_norm(cfg, d: int):
 
 # ----------------------------------------------------------------- rotary
 
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies of the rotary pairs, (head_dim/2,). With a
+    :class:`repro.configs.base.YaRN` ``scaling``: the original frequency
+    below the correction band's low end (fast rotations), the one
+    stretched by ``factor`` above its high end, a linear blend between
+    (DeepSeek-V2's ``yarn_find_correction_range`` and ramp)."""
+    base = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                             / head_dim))
+    if scaling is None:
+        return base
+
+    def corr_dim(rotations):
+        return (head_dim * math.log(scaling.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp            # 1 where the original frequency is kept
+    return base / scaling.factor * (1.0 - keep) + base * keep
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float
-               ) -> jnp.ndarray:
-    """x: (..., S, H, D) rotated pairwise-half style; positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor m = 0.1 mscale ln(factor) + 1 (1 when the
+    context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_cos_scale(scaling) -> float:
+    """What cos and sin are multiplied by: m(mscale) / m(mscale_all_dim)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               scaling=None) -> jnp.ndarray:
+    """x: (..., S, H, D) rotated pairwise-half style; positions: (..., S).
+    ``scaling``: YaRN frequencies and cos/sin factor (see
+    :func:`rope_freqs`)."""
     D = x.shape[-1]
-    freqs = rope_freqs(D, theta)                        # (D/2,)
+    freqs = rope_freqs(D, theta, scaling)               # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     cos = jnp.cos(angles)[..., :, None, :]              # (..., S, 1, D/2)
     sin = jnp.sin(angles)[..., :, None, :]
+    m = rope_cos_scale(scaling)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

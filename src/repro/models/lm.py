@@ -32,10 +32,13 @@ from repro.models import attention as A
 from repro.models import mla as MLA
 from repro.models import ssm as SSM
 from repro.models.mlp import init_mlp, mlp_block
-from repro.models.moe import init_moe, moe_block
+from repro.models.moe import aux_zero, init_moe, moe_block
 from repro.distributed.constrain import shard_batch
 
 Pytree = Any
+
+LAYER_STACKS = ("layers", "dense_layers")   # leaves stacked over layers
+EXPERT_MATRICES = ("wi", "wg", "wo")         # the routed experts' stacks
 
 
 @jax.custom_vjp
@@ -71,7 +74,9 @@ class LanguageModel:
 
     # ------------------------------------------------------------------ init
 
-    def _init_layer(self, key) -> dict:
+    def _init_layer(self, key, dense: bool = False) -> dict:
+        """One layer's params; ``dense``: a leading dense layer of an MoE
+        model (a gated MLP of ``d_ff`` in place of the experts)."""
         cfg, d, dt = self.cfg, self.cfg.d_model, self.dtype
         ks = jax.random.split(key, 4)
         if cfg.family == "ssm":
@@ -85,7 +90,7 @@ class LanguageModel:
             p["attn"] = MLA.init_mla(ks[0], cfg, d, dt)
         else:
             p["attn"] = A.init_attention(ks[0], cfg, d, dt)
-        if cfg.family == "moe":
+        if cfg.family == "moe" and not dense:
             p["moe"] = init_moe(ks[1], cfg, d, dt)
         else:
             p["mlp"] = init_mlp(ks[1], cfg, d, cfg.d_ff, dt)
@@ -94,12 +99,17 @@ class LanguageModel:
     def init(self, key) -> Pytree:
         cfg, d, dt = self.cfg, self.cfg.d_model, self.dtype
         k_emb, k_layers, k_shared, k_out = jax.random.split(key, 4)
+        k_dense = cfg.first_k_dense
         layer_keys = jax.random.split(k_layers, cfg.num_layers)
         params = {
             "embed": L.embed_init(k_emb, cfg.vocab_size, d, dt),
-            "layers": jax.vmap(self._init_layer)(layer_keys),
+            "layers": jax.vmap(self._init_layer)(layer_keys[k_dense:]),
             "final_norm": L.init_norm(cfg, d),
         }
+        if k_dense:
+            params["dense_layers"] = jax.vmap(
+                lambda k: self._init_layer(k, dense=True))(
+                layer_keys[:k_dense])
         if not cfg.tie_embeddings:
             params["unembed"] = L.dense_init(k_out, d, cfg.vocab_size, dt)
         if cfg.family == "hybrid":
@@ -113,10 +123,51 @@ class LanguageModel:
         return params
 
     def stacked_marker(self, params: Pytree) -> Pytree:
-        """Bool pytree: True for (L, ...)-stacked leaves (under 'layers')."""
+        """How many leading axes of each leaf stack slices that the
+        layer-wise optimizers treat apart: 1 for (L, ...) layer stacks
+        ('layers', 'dense_layers'), 2 for the routed experts'
+        (L, E_held, ...) stacks (each expert is its own FFN), 0 else."""
         def mark(path, leaf):
-            return any(getattr(p, "key", None) == "layers" for p in path)
+            keys = [getattr(p, "key", None) for p in path]
+            if not any(k in LAYER_STACKS for k in keys):
+                return 0
+            expert = keys[-2:-1] == ["moe"] and keys[-1] in EXPERT_MATRICES
+            return 2 if expert else 1
         return jax.tree_util.tree_map_with_path(mark, params)
+
+    def _stacks(self, params) -> list:
+        """[(stacked layer params, first layer id, layers)]: the leading
+        dense layers, if any, then the rest."""
+        k = self.cfg.first_k_dense if "dense_layers" in params else 0
+        head = [(params["dense_layers"], 0, k)] if k else []
+        return head + [(params["layers"], k, self.cfg.num_layers - k)]
+
+    def _scan_layers(self, body, carry, params, per_layer=()):
+        """``carry, y = body(carry, (layer params, *per-layer slices,
+        layer id))`` over every layer, stack by stack, as a ``lax.scan``
+        (or unrolled without ``scan_layers``). ``per_layer``: arrays with
+        a leading num_layers axis. Returns (carry, ys over all layers)."""
+        stacks = self._stacks(params)
+        outs = []
+        for sp, lo, n in stacks:
+            sl = tuple(per_layer) if len(stacks) == 1 else tuple(
+                jax.tree_util.tree_map(lambda t: t[lo:lo + n], a)
+                for a in per_layer)
+            if self.cfg.scan_layers:
+                carry, ys = jax.lax.scan(
+                    body, carry, (sp, *sl, jnp.arange(lo, lo + n)))
+            else:   # unrolled: exact per-layer cost accounting
+                ys = []
+                for i in range(n):
+                    xs = jax.tree_util.tree_map(lambda t: t[i], (sp, *sl))
+                    carry, y = body(carry, (*xs, jnp.asarray(lo + i)))
+                    ys.append(y)
+                ys = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
+            outs.append(ys)
+        if len(outs) == 1:
+            return carry, outs[0]
+        return carry, jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *outs)
 
     # ------------------------------------------------------------- embedding
 
@@ -137,7 +188,7 @@ class LanguageModel:
     def _layer_train(self, params_l, x, positions, prefix_len, layer_idx,
                      shared):
         cfg = self.cfg
-        aux = jnp.zeros((), jnp.float32)
+        aux = aux_zero(cfg)
         if cfg.family in ("ssm", "hybrid"):
             h = L.apply_norm(cfg, x, params_l["ln1"])
             fwd = (SSM.mamba1_forward if cfg.family == "ssm"
@@ -158,18 +209,15 @@ class LanguageModel:
         with jax.named_scope(phases.ATTENTION):
             h = L.apply_norm(cfg, x, params_l["ln1"])
             if cfg.use_mla:
-                attn_out = MLA.mla_block(
-                    cfg, params_l["attn"], h,
-                    jnp.arange(h.shape[1]) if positions is None else positions)
+                attn_out = MLA.mla_block(cfg, params_l["attn"], h, positions)
             else:
                 attn_out = A.attention_block(cfg, params_l["attn"], h,
                                              positions, prefix_len=prefix_len)
             x = x + attn_out
         with jax.named_scope(phases.MLP):
             h = L.apply_norm(cfg, x, params_l["ln2"])
-            if cfg.family == "moe":
-                y, moe_aux = moe_block(cfg, params_l["moe"], h)
-                aux = aux + moe_aux["aux_loss"]
+            if "moe" in params_l:
+                y, aux = moe_block(cfg, params_l["moe"], h)
                 x = x + y
             else:
                 x = x + mlp_block(cfg, params_l["mlp"], h)
@@ -208,48 +256,51 @@ class LanguageModel:
             params_l, idx = inp
             x, aux_l = self._layer_train(params_l, x, positions, prefix_len,
                                          idx, shared)
-            return (shard_batch(x), aux + aux_l), None
+            return (shard_batch(x), jax.tree_util.tree_map(
+                jnp.add, aux, aux_l)), None
 
         if cfg.remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
-        carry = (x, jnp.zeros((), jnp.float32))
+        carry = (x, aux_zero(cfg))
         blk = cfg.remat_block
-        if cfg.scan_layers and cfg.remat and blk \
-                and cfg.num_layers % blk == 0:
-            # sqrt-remat: outer scan over L/b checkpointed blocks, inner
-            # scan over b layers. Saved residual-stream carries drop from
-            # L slices to L/b (+ b transiently inside one block's
-            # backward) — the flat-scan carry stack (f32+bf16 copies)
-            # dominates peak train memory at depth 60-81 (§Perf).
-            nb = cfg.num_layers // blk
-            params_b = jax.tree_util.tree_map(
-                lambda t: t.reshape((nb, blk) + t.shape[1:]),
-                params["layers"])
-            idx_b = jnp.arange(cfg.num_layers).reshape(nb, blk)
+        for stack, lo, n in self._stacks(params):
+            if cfg.scan_layers and cfg.remat and blk and n % blk == 0:
+                # sqrt-remat: outer scan over n/b checkpointed blocks,
+                # inner scan over b layers. Saved residual-stream carries
+                # drop from n slices to n/b (+ b transiently inside one
+                # block's backward) — the flat-scan carry stack (f32+bf16
+                # copies) dominates peak train memory at depth 60-81
+                # (§Perf).
+                nb = n // blk
+                params_b = jax.tree_util.tree_map(
+                    lambda t: t.reshape((nb, blk) + t.shape[1:]), stack)
+                idx_b = jnp.arange(lo, lo + n).reshape(nb, blk)
 
-            def outer(c, inp):
-                pb, ib = inp
-                c, _ = jax.lax.scan(body, c, (pb, ib))
-                return c, None
+                def outer(c, inp):
+                    pb, ib = inp
+                    c, _ = jax.lax.scan(body, c, (pb, ib))
+                    return c, None
 
-            outer = jax.checkpoint(
-                outer, policy=jax.checkpoint_policies.nothing_saveable)
-            carry, _ = jax.lax.scan(outer, carry, (params_b, idx_b))
-        elif cfg.scan_layers:
-            carry, _ = jax.lax.scan(
-                body, carry, (params["layers"], jnp.arange(cfg.num_layers)))
-        else:   # unrolled: exact per-layer cost accounting (dry-run probes)
-            for i in range(cfg.num_layers):
-                params_l = jax.tree_util.tree_map(lambda t: t[i],
-                                                  params["layers"])
-                carry, _ = body(carry, (params_l, jnp.asarray(i)))
+                outer = jax.checkpoint(
+                    outer, policy=jax.checkpoint_policies.nothing_saveable)
+                carry, _ = jax.lax.scan(outer, carry, (params_b, idx_b))
+            elif cfg.scan_layers:
+                carry, _ = jax.lax.scan(
+                    body, carry, (stack, jnp.arange(lo, lo + n)))
+            else:   # unrolled: exact per-layer cost accounting (probes)
+                for i in range(n):
+                    params_l = jax.tree_util.tree_map(lambda t: t[i], stack)
+                    carry, _ = body(carry, (params_l, jnp.asarray(lo + i)))
         x, aux = carry
+        if "moe_load_max_over_mean" in aux:     # a mean over MoE layers
+            n_moe = cfg.num_layers - cfg.first_k_dense
+            aux["moe_load_max_over_mean"] = \
+                aux["moe_load_max_over_mean"] / n_moe
         with jax.named_scope(phases.HEAD_LOSS):
             if return_hidden:
-                return L.apply_norm(cfg, x, params["final_norm"]), \
-                    {"aux_loss": aux}
-            return self.logits(params, x), {"aux_loss": aux}
+                return L.apply_norm(cfg, x, params["final_norm"]), aux
+            return self.logits(params, x), aux
 
     def unembed_matrix(self, params):
         return (params["embed"].T if self.cfg.tie_embeddings
@@ -355,7 +406,7 @@ class LanguageModel:
             new_cache_l = dict(cache_l, k=k, v=v)
         x = x + out
         h = L.apply_norm(cfg, x, params_l["ln2"])
-        if cfg.family == "moe":
+        if "moe" in params_l:
             y, _ = moe_block(cfg, params_l["moe"], h)
             x = x + y
         else:
@@ -388,20 +439,8 @@ class LanguageModel:
                 shared_cache, use_flash=use_flash)
             return (x, shared_cache), new_cache_l
 
-        if cfg.scan_layers:
-            (x, shared_cache), new_layer_cache = jax.lax.scan(
-                body, (x, shared_cache),
-                (params["layers"], layer_cache, jnp.arange(cfg.num_layers)))
-        else:
-            carry, outs = (x, shared_cache), []
-            for i in range(cfg.num_layers):
-                sl = jax.tree_util.tree_map(lambda t: t[i],
-                                            (params["layers"], layer_cache))
-                carry, new_cache_l = body(carry, (*sl, jnp.asarray(i)))
-                outs.append(new_cache_l)
-            x, shared_cache = carry
-            new_layer_cache = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *outs)
+        (x, shared_cache), new_layer_cache = self._scan_layers(
+            body, (x, shared_cache), params, (layer_cache,))
 
         new_cache = dict(new_layer_cache)
         new_cache["pos"] = pos + 1
@@ -467,26 +506,14 @@ class LanguageModel:
                 kv_out = {"k": k, "v": v}
             x = x + out
             h = L.apply_norm(cfg, x, params_l["ln2"])
-            if cfg.family == "moe":
+            if "moe" in params_l:
                 y, _ = moe_block(cfg, params_l["moe"], h)
                 x = x + y
             else:
                 x = x + mlp_block(cfg, params_l["mlp"], h)
             return (x,), kv_out
 
-        if cfg.scan_layers:
-            (x,), kvs = jax.lax.scan(body, (x,),
-                                     (params["layers"],
-                                      jnp.arange(cfg.num_layers)))
-        else:
-            carry, outs = (x,), []
-            for i in range(cfg.num_layers):
-                params_l = jax.tree_util.tree_map(lambda t: t[i],
-                                                  params["layers"])
-                carry, kv_out = body(carry, (params_l, jnp.asarray(i)))
-                outs.append(kv_out)
-            (x,) = carry
-            kvs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
+        (x,), kvs = self._scan_layers(body, (x,), params)
 
         logits = self._last_valid_logits(params, x, lengths)
         if cfg.use_mla:
@@ -651,20 +678,8 @@ class LanguageModel:
                 shared_cache)
             return (x, shared_cache), new_cache_l
 
-        if cfg.scan_layers:
-            (x, shared_cache), new_layer_cache = jax.lax.scan(
-                body, (x, shared_cache),
-                (params["layers"], layer_cache, jnp.arange(cfg.num_layers)))
-        else:
-            carry, outs = (x, shared_cache), []
-            for i in range(cfg.num_layers):
-                sl = jax.tree_util.tree_map(lambda t: t[i],
-                                            (params["layers"], layer_cache))
-                carry, new_cache_l = body(carry, (*sl, jnp.asarray(i)))
-                outs.append(new_cache_l)
-            x, shared_cache = carry
-            new_layer_cache = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *outs)
+        (x, shared_cache), new_layer_cache = self._scan_layers(
+            body, (x, shared_cache), params, (layer_cache,))
 
         new_cache = dict(new_layer_cache)
         new_cache["pos"] = start + lengths
@@ -734,7 +749,7 @@ class LanguageModel:
             new_cache_l = dict(cache_l, k=k, v=v)
         x = x + out
         h = L.apply_norm(cfg, x, params_l["ln2"])
-        if cfg.family == "moe":
+        if "moe" in params_l:
             y, _ = moe_block(cfg, params_l["moe"], h)
             x = x + y
         else:

@@ -45,18 +45,35 @@ def init_mla(key, cfg, d: int, dtype) -> dict:
     return p
 
 
-def _queries(cfg, p, x, positions):
+def softmax_scale(cfg) -> float:
+    """(nope + rope)^-0.5, times YaRN's m(mscale_all_dim)^2 where the
+    rope is scaled (DeepSeek-V2's ``softmax_scale``)."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= L.yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def _queries(cfg, p, x, positions, scale=None):
+    """(q_nope, q_rope) per head. ``scale``: folded into both while they
+    are f32, before their one rounding to x.dtype."""
     B, S, _ = x.shape
     H = cfg.num_heads
     nope, rope = cfg.qk_nope_dim, cfg.qk_rope_dim
+    out = None if scale is None else jnp.float32
     if cfg.q_lora_rank:
         cq = L.rmsnorm(x @ p["q_down"], p["q_norm"], cfg.norm_eps)
-        q = cq @ p["q_up"]
+        q = jnp.matmul(cq, p["q_up"], preferred_element_type=out)
     else:
-        q = x @ p["wq"]
+        q = jnp.matmul(x, p["wq"], preferred_element_type=out)
     q = q.reshape(B, S, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta,
+                          cfg.rope_scaling)
+    if scale is not None:
+        q_nope = (q_nope * scale).astype(x.dtype)
+        q_rope = (q_rope * scale).astype(x.dtype)
     return q_nope, q_rope
 
 
@@ -66,19 +83,24 @@ def _latents(cfg, p, x, positions):
     kv = x @ p["kv_down"]
     c_kv, k_rope = kv[..., :r], kv[..., r:]
     c_kv = L.rmsnorm(c_kv, p["kv_norm"], cfg.norm_eps)
-    k_rope = L.apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
+    k_rope = L.apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                          cfg.rope_scaling)
     return c_kv, k_rope
 
 
 def mla_block(cfg, p, x, positions) -> jnp.ndarray:
     """Train/prefill: expanded (naive) form — full K/V materialized per
-    layer, which is fine when activations are remat'd anyway."""
+    layer, which is fine when activations are remat'd anyway. The
+    softmax scale is folded into q (f32, one rounding), so the core
+    runs at scale 1: the causal flash kernels take it on the TPU.
+    ``positions`` None means 0..S-1, a fact the core sees while tracing."""
     B, S, d = x.shape
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
 
-    q_nope, q_rope = _queries(cfg, p, x, positions)
-    c_kv, k_rope = _latents(cfg, p, x, positions)
+    pos = jnp.arange(S) if positions is None else positions
+    q_nope, q_rope = _queries(cfg, p, x, pos, softmax_scale(cfg))
+    c_kv, k_rope = _latents(cfg, p, x, pos)
 
     k_nope = (c_kv @ p["k_up"]).reshape(B, S, H, nope)
     v = (c_kv @ p["v_up"]).reshape(B, S, H, vd)
@@ -88,7 +110,7 @@ def mla_block(cfg, p, x, positions) -> jnp.ndarray:
 
     with jax.named_scope(phases.ATTENTION_CORE):
         out = attention_core(q, k, v, q_positions=positions, causal=True,
-                             scale=(nope + rope) ** -0.5,
+                             scale=1.0,
                              q_chunk=cfg.attn_q_chunk,
                              flash_vjp=cfg.flash_vjp)
     return out.reshape(B, S, H * vd) @ p["wo"]
@@ -117,7 +139,7 @@ def mla_decode(cfg, p, x, cache_ckv, cache_krope, pos):
                         cache_ckv.astype(jnp.float32))
     scores += jnp.einsum("bohe,bse->bhs", q_rope.astype(jnp.float32),
                          cache_krope.astype(jnp.float32))
-    scores *= (nope + rope) ** -0.5
+    scores *= softmax_scale(cfg)
     valid = jnp.arange(S)[None, None, :] < (pos[:, None, None] + 1)
     scores = jnp.where(valid, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)                 # (B,H,S)
@@ -156,7 +178,7 @@ def mla_chunk(cfg, p, x, cache_ckv, cache_krope, qpos, start, lengths):
                         cache_ckv.astype(jnp.float32))
     scores += jnp.einsum("bche,bse->bhcs", q_rope.astype(jnp.float32),
                          cache_krope.astype(jnp.float32))
-    scores *= (nope + rope) ** -0.5
+    scores *= softmax_scale(cfg)
     kp = jnp.arange(S)[None, None, None, :]                 # linear cache
     qp = qpos[:, None, :, None]
     kv_len = (start + lengths)[:, None, None, None]

@@ -1,38 +1,79 @@
-"""Mixture-of-Experts block: top-k token-choice router, capacity-bounded
-sort-based dispatch, optional shared experts (DeepSeek-V2 style), and a
-Switch-style load-balance auxiliary loss.
+"""Mixture-of-Experts layer: a softmax router over all E experts with
+greedy top-k, a dropless grouped-matmul dispatch over the experts this
+layer holds, optional shared experts (DeepSeek-V2), and a load-balance
+loss (DeepSeek's per-sequence form or Switch's).
 
-Why sort-based dispatch
------------------------
-The classic Mesh-TF one-hot dispatch materializes a (tokens, E, C) tensor
-— at deepseek-v2 train shapes that is ~3e13 elements per shard. Instead we
-  1. top-k route: (N, k) expert ids + gates,
-  2. flatten to N*k slots, argsort by expert id (XLA sort, shardable),
-  3. compute each slot's position within its expert via a sorted cumsum,
-  4. scatter slot->`(E*C)` index map, gather tokens into (E, C, d),
-  5. batched per-expert matmuls  (E, C, d) x (E, d, ff)  — experts shard
-     over the `model` mesh axis (expert parallelism; XLA inserts the
-     all-to-alls implied by resharding tokens->experts->tokens),
-  6. combine: gather back + weighted sum over k.
+Which experts a layer holds
+---------------------------
+Expert parallelism divides each layer's E routed experts over chips, and
+the layer on one chip holds ``experts_held`` of them, ids
+``expert_offset .. expert_offset + experts_held - 1`` (all E where
+``experts_held`` is 0). It routes every token over all E, keeps the
+(token, slot) pairs whose expert it holds and computes their part of
+the result. The exchange that brings the other chips' parts is not
+here: on one chip there is nothing to exchange with. With every expert
+held this is the whole layer.
 
-Tokens beyond an expert's capacity C = round(k * N/E * capacity_factor)
-are dropped (standard capacity semantics; counted in aux metrics).
+Dispatch: no capacity, no token dropped
+---------------------------------------
+  1. top-k route: (N, k) expert ids and gates;
+  2. each of the N*k slots gets the local id of its expert, or E_held
+     where this layer does not hold it; a stable argsort by that id puts
+     each held expert's slots together and the others last;
+  3. gather the slots' token rows into (N*k, d) and run one grouped
+     matmul (``jax.lax.ragged_dot``) for each of wg, wi and wo over the
+     held experts' group sizes; rows past the last group are zeroed;
+  4. combine: each token sums its slots' rows times their gates.
+
+The buffer has N*k rows whatever the routing, so no routing, however
+skewed, can overflow it; the grouped matmuls work on the rows that
+their groups cover.
+
+Balance loss: with ``seq_aux`` DeepSeek-V2's per-sequence form
+(arXiv:2405.04434 §4.2.2), alpha * mean over rows of sum_i f_i P_i with
+f_i = E / (k S) * (slots of the row routed to i) and P_i the row's mean
+router probability; else Switch's, alpha * E * sum_i f_i P_i with f_i
+the share of the batch's tokens routed to i (summing to k) and P_i the
+batch's mean probability. The two are the same quantity up to a factor
+of 1/k and the grouping of tokens (a sequence against the whole batch).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from repro import phases
 from repro.models import layers as L
 from repro.models.mlp import init_mlp, mlp_block
 
 
+# routing counters of a forward, beside its balance loss: rows the held
+# experts computed (summed over layers), and the busiest held expert's
+# rows over the mean (averaged over layers)
+MOE_COUNTERS = ("moe_rows", "moe_load_max_over_mean")
+
+
+def aux_zero(cfg) -> dict:
+    """Zeros of the aux values a forward reports: the balance loss, and
+    for MoE models the routing counters."""
+    names = ("aux_loss",) + (MOE_COUNTERS if cfg.family == "moe" else ())
+    return {k: jnp.zeros((), jnp.float32) for k in names}
+
+
+def held_experts(cfg) -> int:
+    """Routed experts this layer holds."""
+    return cfg.experts_held or cfg.num_experts
+
+
 def init_moe(key, cfg, d: int, dtype) -> dict:
-    E, ff = cfg.num_experts, cfg.moe_d_ff
+    E, ff = held_experts(cfg), cfg.moe_d_ff
     ks = jax.random.split(key, 5)
     p = {
-        "router": L.dense_init(ks[0], d, E, jnp.float32, scale=0.1),
+        "router": L.dense_init(ks[0], d, cfg.num_experts, jnp.float32,
+                               scale=0.1),
         "wi": _stack_init(ks[1], E, d, ff, dtype),
         "wo": _stack_init(ks[2], E, ff, d, dtype),
     }
@@ -50,97 +91,116 @@ def _stack_init(key, E, d_in, d_out, dtype):
             * std).astype(dtype)
 
 
-def moe_block(cfg, p, x) -> tuple[jnp.ndarray, dict]:
-    """x (B, S, d) -> (out (B, S, d), aux {aux_loss, dropped_frac}).
-
-    ``cfg.moe_groups`` > 1 splits the token set into G independent
-    dispatch groups (vmapped). With G = number of data shards, routing /
-    sort / capacity buffers are shard-LOCAL: the (G, E, C_g, d) buffer
-    shards as (data, model, ..., ...) and the only cross-device movement
-    is the token->expert all-to-all GSPMD inserts around the expert
-    matmuls — this is how production MoE keeps dispatch off the global
-    batch (DESIGN.md §6).
-    """
-    B, S, d = x.shape
-    G = max(1, cfg.moe_groups)
-    N = B * S
-    assert N % G == 0, (N, G)
-    xg = x.reshape(G, N // G, d)
-    out, aux = jax.vmap(lambda xt: _moe_group(cfg, p, xt))(xg)
-    if cfg.num_shared_experts:
-        xt = x.reshape(N, d)
-        shared = mlp_block(cfg, p["shared"], xt)
-        out = out.reshape(N, d) + shared.astype(out.dtype)
-    return (out.reshape(B, S, d).astype(x.dtype),
-            {"aux_loss": jnp.mean(aux["aux_loss"]),
-             "dropped_frac": jnp.mean(aux["dropped_frac"])})
-
-
-def _moe_group(cfg, p, xt) -> tuple[jnp.ndarray, dict]:
-    """One dispatch group. xt (N, d) -> (out (N, d), aux)."""
-    N, d = xt.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-
-    logits = (xt.astype(jnp.float32) @ p["router"])          # (N, E)
+def route(cfg, router, x):
+    """x (B, S, d) -> probs (B, S, E) f32 over all E experts, and the
+    greedy top-k (ids, gates), each (B, S, k)."""
+    logits = x.astype(jnp.float32) @ router
     probs = jax.nn.softmax(logits, axis=-1)
-    gates, expert_ids = jax.lax.top_k(probs, k)              # (N, k)
-    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    gates, ids = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-20)
+    return probs, ids, gates * cfg.routed_scaling_factor
 
-    # ---- load-balance aux loss (Switch): E * sum_e f_e * P_e
-    me = jnp.mean(probs, axis=0)                             # (E,)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(expert_ids, E, dtype=jnp.float32), axis=1),
-        axis=0)                                              # (E,)
-    aux_loss = E * jnp.sum(me * ce)
 
-    # ---- capacity
-    C = int(max(1, round(k * N / E * cfg.capacity_factor)))
+def balance_loss(cfg, probs, ids):
+    """The un-weighted balance loss of routing (B, S, E) probs to
+    (B, S, k) ids (module docstring)."""
+    E, k = cfg.num_experts, cfg.experts_per_token
+    counts = jnp.sum(jax.nn.one_hot(ids, E, dtype=jnp.float32), axis=2)
+    if cfg.seq_aux:
+        f = jnp.sum(counts, axis=1) * (E / (k * ids.shape[1]))   # (B, E)
+        return jnp.mean(jnp.sum(f * jnp.mean(probs, axis=1), -1))
+    f = jnp.mean(counts, axis=(0, 1))                           # (E,)
+    return E * jnp.sum(f * jnp.mean(probs, axis=(0, 1)))
 
-    # ---- sort slots by expert
-    slot_expert = expert_ids.reshape(-1)                     # (N*k,)
-    slot_token = jnp.repeat(jnp.arange(N), k)
-    slot_gate = gates.reshape(-1)
-    order = jnp.argsort(slot_expert)
-    se, st, sg = slot_expert[order], slot_token[order], slot_gate[order]
 
-    # position of each sorted slot within its expert
-    same = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                            (se[1:] == se[:-1]).astype(jnp.int32)])
-    seg_start = jnp.where(same == 0, jnp.arange(se.shape[0]), 0)
-    seg_start = jax.lax.associative_scan(jnp.maximum, seg_start)
-    pos_in_expert = jnp.arange(se.shape[0]) - seg_start
+def held_slots(cfg, ids):
+    """(N*k,) local expert id of every slot, E_held where not held."""
+    E = held_experts(cfg)
+    local = ids.reshape(-1) - cfg.expert_offset
+    return jnp.where((local >= 0) & (local < E), local, E)
 
-    keep = pos_in_expert < C
-    dropped_frac = 1.0 - jnp.mean(keep.astype(jnp.float32))
 
-    # slot -> (E*C) buffer index; dropped slots land in a trash row
-    buf_idx = jnp.where(keep, se * C + pos_in_expert, E * C)
-
-    # gather tokens into expert buffers: (E*C+1,) -> source token index
-    src = jnp.full((E * C + 1,), N, jnp.int32).at[buf_idx].set(st)
-    xg = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)])  # trash token
-    xe = xg[src[:-1]].reshape(E, C, d)
-
-    # ---- per-expert matmuls (expert-parallel over `model` axis)
+def grouped_mlp(cfg, p, xs, sizes):
+    """The held experts' gated MLP over rows ``xs`` (M, d) sorted by
+    expert, ``sizes`` (E_held,) rows each: one ``ragged_dot`` per
+    weight."""
     act = L.act_fn(cfg)
-    h = jnp.einsum("ecd,edf->ecf", xe, p["wi"])
+    h = jax.lax.ragged_dot(xs, p["wi"], sizes)
     if "wg" in p:
-        h = act(jnp.einsum("ecd,edf->ecf", xe, p["wg"])) * h
+        h = act(jax.lax.ragged_dot(xs, p["wg"], sizes)) * h
     else:
         h = act(h)
-    ye = jnp.einsum("ecf,efd->ecd", h, p["wo"])              # (E, C, d)
+    return jax.lax.ragged_dot(h, p["wo"], sizes)
 
-    # ---- combine back to tokens (weighted scatter-add over kept slots)
-    # Model-dtype (bf16) end-to-end: §Perf deepseek iteration 1 tested an
-    # f32 combine and confirmed the on-wire dtype of the slot collectives
-    # is set by XLA's fusion of the surrounding converts, not by this
-    # multiply — keep the cheaper bf16 math.
-    ye_flat = ye.reshape(E * C, d)
-    slot_out = ye_flat[jnp.clip(buf_idx, 0, E * C - 1)]      # (Nk, d) sorted
-    contrib = slot_out * sg[:, None].astype(slot_out.dtype)
-    out = jnp.zeros((N, d), contrib.dtype).at[st].add(
-        jnp.where(keep[:, None], contrib, 0))
 
-    aux = {"aux_loss": aux_loss * cfg.router_aux_coef,
-           "dropped_frac": dropped_frac}
-    return out, aux
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather(x, perm, inv, fan: int):
+    """x[perm // fan], where ``perm`` permutes range(fan * len(x)) and
+    ``inv`` is its inverse. The gradient gathers by ``inv`` and sums the
+    ``fan`` copies of each row, where that of an indexed read would
+    scatter-add row by row (slow on the TPU)."""
+    return x[perm // fan]
+
+
+def _gather_fwd(x, perm, inv, fan):
+    return x[perm // fan], inv
+
+
+def _gather_bwd(fan, inv, g):
+    rows = g[inv].astype(jnp.float32).reshape(-1, fan, g.shape[-1])
+    return jnp.sum(rows, axis=1).astype(g.dtype), None, None
+
+
+_gather.defvjp(_gather_fwd, _gather_bwd)
+
+
+def _dispatch(cfg, p, xt, ids, gates):
+    """xt (N, d), ids/gates (N, k) -> (out (N, d), rows each held
+    expert computed (E_held,))."""
+    N, d = xt.shape
+    k = ids.shape[1]
+    E = held_experts(cfg)
+    with jax.named_scope(phases.MOE_DISPATCH):
+        local = held_slots(cfg, ids)
+        order = jnp.argsort(local, stable=True)
+        sizes = jnp.bincount(local, length=E + 1)[:E].astype(jnp.int32)
+        # rows past the held ones belong to no group: the TPU's grouped
+        # matmul leaves them unwritten, in its outputs and in the
+        # gradient it hands back, so both sides are masked
+        live = (jnp.arange(N * k) < jnp.sum(sizes))[:, None]
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        xs = jnp.where(live, _gather(xt, order, back, k), 0)   # (N*k, d)
+    with jax.named_scope(phases.MOE_EXPERTS):
+        ys = grouped_mlp(cfg, p, xs, sizes)
+    with jax.named_scope(phases.MOE_DISPATCH):
+        ys = jnp.where(live, ys, 0)
+        y = _gather(ys, back, order, 1).reshape(N, k, d)
+        out = jnp.einsum("nkd,nk->nd", y, gates.astype(y.dtype),
+                         preferred_element_type=jnp.float32)
+    return out.astype(xt.dtype), sizes
+
+
+def moe_block(cfg, p, x) -> tuple[jnp.ndarray, dict]:
+    """x (B, S, d) -> (out (B, S, d), aux): ``aux_loss`` the weighted
+    balance loss, ``moe_rows`` the rows the held experts computed and
+    ``moe_load_max_over_mean`` the busiest held expert's rows over the
+    mean.
+    """
+    B, S, d = x.shape
+    N = B * S
+    with jax.named_scope(phases.ROUTER):
+        probs, ids, gates = route(cfg, p["router"], x)
+        aux_loss = cfg.router_aux_coef * balance_loss(cfg, probs, ids)
+    k = ids.shape[-1]
+    xt, ids, gates = x.reshape(N, d), ids.reshape(N, k), gates.reshape(N, k)
+    out, sizes = _dispatch(cfg, p, xt, ids, gates)
+    if cfg.num_shared_experts:
+        out = out + mlp_block(cfg, p["shared"], xt).astype(out.dtype)
+    rows = jnp.sum(sizes).astype(jnp.float32)
+    load = jnp.where(rows > 0, jnp.max(sizes) * sizes.shape[0]
+                     / jnp.maximum(rows, 1.0), 0.0)
+    return (out.reshape(B, S, d),
+            {"aux_loss": aux_loss, "moe_rows": rows,
+             "moe_load_max_over_mean": load})

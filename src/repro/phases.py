@@ -14,3 +14,6 @@ LARS_NORMS = "lars_norms"  # per-slice weight and update norms
 TRUST_RATIO = "trust_ratio"  # trust ratios and the adaptation mask
 LARS_APPLY = "lars_apply"  # momentum, decay and weight update
 PARAM_UNPACK = "param_unpack"  # new master weights to storage-dtype params
+ROUTER = "router"  # an MoE layer's router: logits, top-k gates, balance loss
+MOE_DISPATCH = "moe_dispatch"  # sort of the held slots, gather, combine
+MOE_EXPERTS = "moe_experts"  # the held experts' grouped matmuls

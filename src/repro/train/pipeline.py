@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from repro import phases
 from repro.core import packing
 from repro.core.optim_base import PackedGrads
+from repro.models.moe import MOE_COUNTERS, aux_zero
 from repro.train.state import TrainState
 from repro.train.step import _forward_and_loss
 
@@ -116,6 +117,21 @@ def compiled_peak_bytes(compiled) -> Optional[int]:
         return None
     return int(mem.temp_size_in_bytes + mem.argument_size_in_bytes
                + mem.output_size_in_bytes)
+
+
+# -------------------------------------------------------------- metrics
+
+# per-step values the forward reports beside the loss; each a mean over
+# microbatches except these sums (rows the held experts computed)
+SUMMED = ("moe_rows",)
+
+
+def _aux_sums(aux: dict) -> dict:
+    """The forward's aux values a step reports: the balance loss (zero
+    where the model has none) and an MoE model's routing counters."""
+    out = {"aux_loss": aux.get("aux_loss", jnp.zeros((), jnp.float32))}
+    out.update({k: aux[k] for k in MOE_COUNTERS if k in aux})
+    return out
 
 
 # -------------------------------------------------------------- pipeline
@@ -274,7 +290,7 @@ class TrainPipeline:
             if k == 1:
                 # exactly make_train_step's body: bit-identical under f32
                 (loss, (_, aux)), grads = grad_fn(state.params, batch)
-                aux_loss = aux.get("aux_loss", jnp.zeros((), jnp.float32))
+                aux = _aux_sums(aux)
             else:
                 micro = tree_map(
                     lambda x: x.reshape((k, x.shape[0] // k) + x.shape[1:]),
@@ -283,6 +299,7 @@ class TrainPipeline:
                 def body(carry, mb):
                     gsum, lsum, asum = carry
                     (loss, (_, aux)), g = grad_fn(state.params, mb)
+                    aux = _aux_sums(aux)
                     if fuse:
                         # accumulate in packed form: pack casts to f32
                         # BEFORE adding, so every element sees the same
@@ -295,8 +312,7 @@ class TrainPipeline:
                         gsum = tree_map(
                             lambda a, gi: a + gi.astype(jnp.float32),
                             gsum, g)
-                    asum = asum + aux.get("aux_loss",
-                                          jnp.zeros((), jnp.float32))
+                    asum = tree_map(jnp.add, asum, aux)
                     return (gsum, lsum + loss, asum), None
 
                 zeros = jnp.zeros(layout.buffer_shape, jnp.float32) \
@@ -304,7 +320,7 @@ class TrainPipeline:
                         lambda p: jnp.zeros(p.shape, jnp.float32),
                         state.params)
                 carry0 = (zeros, jnp.zeros((), jnp.float32),
-                          jnp.zeros((), jnp.float32))
+                          aux_zero(cfg))
                 (gsum, lsum, asum), _ = jax.lax.scan(body, carry0, micro)
                 # equal-size microbatches + mean losses: the mean of the
                 # per-microbatch mean gradients IS the full-batch mean
@@ -316,12 +332,13 @@ class TrainPipeline:
                         grads = PackedGrads(gsum * inv)
                 else:
                     grads = tree_map(lambda g: g * inv, gsum)
-                loss, aux_loss = lsum * inv, asum * inv
+                loss = lsum * inv
+                aux = {name: v if name in SUMMED else v * inv
+                       for name, v in asum.items()}
 
             new_params, new_opt = optimizer.update(
                 grads, state.opt_state, state.params, stacked=stacked)
-            metrics = {"loss": loss, "aux_loss": aux_loss,
-                       "step": new_opt.step}
+            metrics = dict(aux, loss=loss, step=new_opt.step)
             if stats_fn is not None:
                 stat_grads = packing.unpack(layout, grads.buf,
                                             dtype=jnp.float32) \

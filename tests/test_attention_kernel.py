@@ -87,6 +87,12 @@ def test_eligible_input_takes_the_kernel_on_tpu(spy):
                        flash_vjp=True)
 
 
+def test_mla_head_dims_take_the_kernel_on_tpu(spy):
+    """MLA: q and k of 192, v of 128, one query head per KV head, the
+    YaRN scale folded into q by the caller (scale 1)."""
+    assert took_kernel(spy, *qkv(2, 512, 4, 4, 192, Dv=128), scale=1.0)
+
+
 def test_eligible_input_keeps_the_jnp_path_off_tpu(monkeypatch):
     calls = []
     monkeypatch.setattr(CA, "causal_attention",
@@ -105,7 +111,6 @@ INELIGIBLE = {
     "softcap": ({}, dict(softcap=30.0)),
     "positions_given": ({}, dict(q_positions=jnp.arange(S))),
     "sq_ne_sk": (dict(Sk=2 * S), {}),
-    "dv_ne_d": (dict(Dv=32), {}),
     "decode": (dict(S=1, Sk=S), dict(kv_len=jnp.full((B,), S))),
     "not_a_block": (dict(S=S + 64), {}),
     "inexact_scale": ({}, dict(scale=D ** -0.5 * 0.9)),

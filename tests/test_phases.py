@@ -24,6 +24,8 @@ from repro.models import build_model
 from repro.train import TrainPipeline
 
 PHASES = [v for k, v in vars(phases).items() if k.isupper()]
+MOE_PHASES = [phases.ROUTER, phases.MOE_DISPATCH, phases.MOE_EXPERTS]
+DENSE_PHASES = [p for p in PHASES if p not in MOE_PHASES]   # smollm's step
 WRAPPER = re.compile(r"^(?:[\w-]+\()+|\)+$")
 
 
@@ -50,8 +52,9 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def compiled_step(accum_steps: int, use_pallas, slot_dtype: str = "f32"):
-    cfg = get_config("smollm-135m").reduced()
+def compiled_step(accum_steps: int, use_pallas, slot_dtype: str = "f32",
+                  arch: str = "smollm-135m"):
+    cfg = get_config(arch).reduced()
     assert cfg.remat and cfg.scan_layers
     model = build_model(cfg)
     pipe = TrainPipeline(model, lars(0.1, use_pallas=use_pallas,
@@ -67,7 +70,7 @@ def with_phase(names: set, phase: str) -> list:
 
 
 def test_phase_names_are_distinct_and_plain():
-    assert len(set(PHASES)) == len(PHASES) == 10
+    assert len(set(PHASES)) == len(PHASES) == 13
     assert all(re.fullmatch(r"[a-z_]+", p) for p in PHASES)
 
 
@@ -76,7 +79,7 @@ def test_every_phase_names_ops_of_the_compiled_step(accum_steps):
     """accum_steps 2 packs each microbatch's gradients in the scan body
     (the fused accumulation); 1 packs them in the optimizer's engine."""
     names = compiled_step(accum_steps, "auto")
-    missing = [p for p in PHASES if not with_phase(names, p)]
+    missing = [p for p in DENSE_PHASES if not with_phase(names, p)]
     assert not missing, missing
     for p in (phases.ATTENTION_CORE, phases.MLP):
         mine = with_phase(names, p)
@@ -97,13 +100,27 @@ def test_lars_phases_wrap_the_pallas_kernels(slot_dtype, apply_kernel):
     """Forced onto the Pallas kernels (interpret mode here), the norms
     and apply phases hold the kernels' own ops, named by the kernels."""
     names = compiled_step(2, True, slot_dtype)
-    missing = [p for p in PHASES if not with_phase(names, p)]
+    missing = [p for p in DENSE_PHASES if not with_phase(names, p)]
     assert not missing, missing
     for phase, kernel in ((phases.LARS_NORMS, "lars_norms"),
                           (phases.LARS_APPLY, apply_kernel)):
         assert any(f"/{phase}/{kernel}/" in n for n in names), kernel
     assert any("/lars_apply_q8/" in n for n in names) == (
         slot_dtype == "int8")
+
+
+def test_moe_phases_name_ops_of_the_compiled_step():
+    """An MoE model's step (DeepSeek-V2-Lite, reduced: a dense layer and
+    an MoE layer) has every phase: the router, dispatch and grouped
+    matmuls nest in the MLP phase, forward, remat and backward."""
+    names = compiled_step(2, "auto", arch="deepseek-v2-lite")
+    missing = [p for p in PHASES if not with_phase(names, p)]
+    assert not missing, missing
+    for p in MOE_PHASES:
+        mine = with_phase(names, p)
+        assert all(phases.MLP in components(n) for n in mine), p
+        assert any("transpose(" in n for n in mine), p
+        assert any("rematted_computation" in n for n in mine), p
 
 
 def test_pipeline_marks_each_step_for_the_profiler(tmp_path):

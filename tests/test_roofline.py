@@ -50,32 +50,23 @@ def test_roofline_terms_and_dominant():
     assert r.useful_flops_ratio == pytest.approx(0.5)
 
 
-class FakeMesh:
-    shape = {"data": 16, "model": 16}
-    axis_names = ("data", "model")
-    size = 256
-
-
 def test_adapt_config_long_context_window():
     shape = get_shape("long_500k")
-    dense = adapt_config(get_config("qwen2-72b"), shape, FakeMesh())
+    dense = adapt_config(get_config("qwen2-72b"), shape)
     assert dense.sliding_window == 8192
-    mla = adapt_config(get_config("deepseek-v2-236b"), shape, FakeMesh())
+    mla = adapt_config(get_config("deepseek-v2-236b"), shape)
     assert mla.sliding_window == 0            # MLA keeps the full cache
-    hyb = adapt_config(get_config("zamba2-7b"), shape, FakeMesh())
+    hyb = adapt_config(get_config("zamba2-7b"), shape)
     assert hyb.sliding_window == 8192         # shared-attn window
-    ssm = adapt_config(get_config("falcon-mamba-7b"), shape, FakeMesh())
+    ssm = adapt_config(get_config("falcon-mamba-7b"), shape)
     assert ssm.sliding_window == 0            # attention-free
 
 
-def test_adapt_config_moe_groups():
+def test_adapt_config_keeps_config_without_carve_out():
     shape = get_shape("train_4k")
-    moe = adapt_config(get_config("granite-moe-3b-a800m"), shape,
-                       FakeMesh())
-    assert moe.moe_groups == 16
-    one = adapt_config(get_config("deepseek-v2-236b"),
-                       get_shape("long_500k"), FakeMesh())
-    assert one.moe_groups == 1                # batch 1 x 1 token
+    for arch in ("granite-moe-3b-a800m", "deepseek-v2-lite", "qwen2-72b"):
+        cfg = get_config(arch)
+        assert adapt_config(cfg, shape) is cfg
 
 
 def test_train_batch_specs_shapes():

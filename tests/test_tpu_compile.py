@@ -172,3 +172,47 @@ def test_packed_lars_update_compiles_on_four_chip_mesh(topo, monkeypatch):
         lowered = jax.jit(lambda g, s, p: opt.update(g, s, p)).lower(*args)
     text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") == 2
+
+
+def test_mla_causal_attention_compiles_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's training attention, as its cell runs it: a
+    microbatch of 1 x 4096 tokens, 16 heads of 192 (q, k) and 128 (v),
+    scale folded into q."""
+    B, S, H = 1, 4096, 16
+
+    def loss(q, k, v):
+        return jnp.sum(CA.causal_attention(q, k, v, scale=1.0,
+                                           interpret=False
+                                           ).astype(jnp.float32))
+
+    def spec(D):
+        return jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    _compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), spec(192),
+                      spec(192), spec(128),
+                      kernel=["splash_mqa_fwd_residuals",
+                              "splash_mqa_dkv_no_residuals"])
+
+
+def test_moe_grouped_matmuls_compile_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's held experts, forward and backward, at its
+    cell's microbatch: 1 x 4096 tokens x 6 slots over 8 experts of 1408;
+    the grouped matmuls keep XLA's ragged-dot kernels."""
+    from repro.configs import get_config
+    from repro.models import moe
+    cfg = get_config("deepseek-v2-lite")
+    M, d, ff, E = 4096 * 6, 2048, 1408, 8
+
+    def loss(p, xs, sizes):
+        return jnp.sum(moe.grouped_mlp(cfg, p, xs, sizes).astype(
+            jnp.float32))
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {"wi": spec((E, d, ff)), "wg": spec((E, d, ff)),
+         "wo": spec((E, ff, d))}
+    _compile_for_chip(jax.grad(loss, argnums=(0, 1)), p, spec((M, d)),
+                      spec((E,), jnp.int32),
+                      kernel=["ragged-dot-metadata", "ragged-dot-none"])
