@@ -18,6 +18,11 @@ kernels' ops ``splash_mqa_fwd_residuals`` (forward under autodiff),
 ``splash_mqa_fwd_no_residuals`` (inference) and
 ``splash_mqa_dkv_no_residuals`` (backward).
 
+Under autodiff the forward tags its two residuals, the output and the
+logsumexp, with :data:`RESIDUALS` (``jax.ad_checkpoint.checkpoint_name``):
+a remat policy that saves that name keeps them, and the backward kernel
+then needs no second forward.
+
 Splash takes no softmax scale; it is folded into q before the kernel.
 That is exact only where the scale is a power of two (head_dim 64:
 1/8), so :func:`supports` accepts no other scale. A caller whose scale
@@ -45,6 +50,10 @@ from repro.kernels.flash_decode import default_interpret
 # training shapes (block sweep in PERF.md).
 FWD_BLOCKS = (512, 256, 128)
 BWD_BLOCKS = (1024, 512, 256, 128)
+
+# checkpoint name of the forward's output and logsumexp (the residuals
+# the backward kernel reads); the layer remat in ``models/lm`` saves it
+RESIDUALS = "attention_residuals"
 
 
 def _largest(blocks, seq: int) -> int:
@@ -84,7 +93,8 @@ def _kernel(seq: int, groups: int, interpret: bool):
         block_q_dkv=bwd, block_kv_dkv=bwd, block_kv_dkv_compute=bwd,
         use_fused_bwd_kernel=True)
     return splash.make_splash_mqa_single_device(
-        mask, block_sizes=sizes, interpret=interpret)
+        mask, block_sizes=sizes, residual_checkpoint_name=RESIDUALS,
+        interpret=interpret)
 
 
 def causal_attention(q, k, v, *, scale: float,

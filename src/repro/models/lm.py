@@ -6,8 +6,11 @@ Key structural decisions:
     loop is a `lax.scan` — keeps HLO size O(1) in depth (mandatory for
     compiling 60-81-layer configs 80 times in the dry-run) and is what the
     LARS `stacked` marker machinery exists for;
-  * remat (`jax.checkpoint`) wraps the scan body, policy `nothing_saveable`
-    by default — residual-stream inputs are the only per-layer live values;
+  * remat (`jax.checkpoint`) wraps the scan body, saving only the attention
+    kernel's output and logsumexp (`causal_attention.RESIDUALS`) beside the
+    residual-stream inputs, so the backward recomputes the layer but not
+    the kernel's forward; where no kernel runs nothing carries the name
+    and nothing is saved;
   * hybrid (zamba2): every `attn_every`-th scan step additionally applies a
     SHARED full attention+MLP block (same weights each application, its own
     KV cache per application) via `lax.cond` — the Zamba2 pattern;
@@ -27,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import phases
+from repro.kernels import causal_attention as CA
 from repro.models import layers as L
 from repro.models import attention as A
 from repro.models import mla as MLA
@@ -39,6 +43,8 @@ Pytree = Any
 
 LAYER_STACKS = ("layers", "dense_layers")   # leaves stacked over layers
 EXPERT_MATRICES = ("wi", "wg", "wo")         # the routed experts' stacks
+# the layer body's remat policy: keep the attention kernel's residuals
+LAYER_REMAT = jax.checkpoint_policies.save_only_these_names(CA.RESIDUALS)
 
 
 @jax.custom_vjp
@@ -260,8 +266,7 @@ class LanguageModel:
                 jnp.add, aux, aux_l)), None
 
         if cfg.remat:
-            body = jax.checkpoint(
-                body, policy=jax.checkpoint_policies.nothing_saveable)
+            body = jax.checkpoint(body, policy=LAYER_REMAT)
         carry = (x, aux_zero(cfg))
         blk = cfg.remat_block
         for stack, lo, n in self._stacks(params):

@@ -11,9 +11,11 @@ take bf16 operands and return bf16, so each rounds its outputs once.
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src.ad_checkpoint import saved_residuals
 from jax.sharding import AbstractMesh
 
 from repro.kernels import causal_attention as CA
+from repro.models import lm
 from repro.models.attention import attention_core
 
 TOL = 2 * float(jnp.finfo(jnp.bfloat16).eps)
@@ -133,3 +135,98 @@ def test_multi_device_mesh_keeps_the_jnp_path(spy):
     with jax.sharding.use_abstract_mesh(
             AbstractMesh((2, 1), ("data", "model"))):
         assert not took_kernel(spy, *qkv(B, S, H, HKV, D))
+
+
+# ----------------------------------------------------------- layer remat
+
+def pallas_calls(jaxpr) -> list:
+    """Names of every ``pallas_call`` in a jaxpr and its sub-jaxprs."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += pallas_calls(sub)
+    return names
+
+
+def remat_layer(policy, attend):
+    """A layer's attention under the given remat policy: q projected from
+    the input, then attention against k and v."""
+    def body(x, wq, k, v):
+        q = (x @ wq).reshape(x.shape[0], x.shape[1], -1, k.shape[-1])
+        o = attend(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+    return jax.checkpoint(body, policy=policy)
+
+
+def layer_inputs(B=1, S=256, H=4, Hkv=2, D=64):
+    x, k, v = qkv(B, S, H, Hkv, D)
+    wq = 0.05 * jax.random.normal(jax.random.key(3), (H * D, H * D),
+                                  jnp.bfloat16)
+    return x.reshape(B, S, H * D), wq, k, v
+
+
+def computed_residuals(f, *args) -> list:
+    """(dtype, shape) of what ``f``'s backward keeps beyond its arguments
+    and constants, sorted."""
+    return sorted((str(a.dtype), a.shape) for a, why in
+                  saved_residuals(f, *args)
+                  if not why.startswith(("from the argument",
+                                         "from a constant")))
+
+
+def test_layer_remat_keeps_the_kernels_residuals():
+    """The model's layer remat saves the kernel's output and logsumexp,
+    so the backward runs the forward kernel once and not twice, and the
+    gradients are those of saving nothing, bit for bit."""
+    B, S, H, Hkv, D = 1, 256, 4, 2, 64
+    args = layer_inputs(B, S, H, Hkv, D)
+
+    def kernel(q, k, v):
+        return CA.causal_attention(q, k, v, scale=D ** -0.5, interpret=True)
+
+    kept = remat_layer(lm.LAYER_REMAT, kernel)
+    recomputed = remat_layer(jax.checkpoint_policies.nothing_saveable,
+                             kernel)
+    G = H // Hkv
+    assert computed_residuals(kept, *args) == sorted(
+        [("bfloat16", (B * Hkv, G, S, D)), ("float32", (B * Hkv, G, S))])
+    assert computed_residuals(recomputed, *args) == []
+
+    def forwards(f):
+        jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2, 3)))(*args)
+        return pallas_calls(jaxpr.jaxpr).count("splash_mqa_fwd_residuals")
+
+    assert forwards(kept) == 1
+    assert forwards(recomputed) == 2
+    grads = jax.jit(jax.grad(kept, argnums=(0, 1, 2, 3)))(*args)
+    refs = jax.jit(jax.grad(recomputed, argnums=(0, 1, 2, 3)))(*args)
+    for g, r in zip(grads, refs):
+        assert g.dtype == r.dtype and bool(jnp.array_equal(g, r))
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "deepseek-v2-lite"])
+def test_lm_remat_off_the_kernel_saves_what_nothing_saveable_saves(
+        monkeypatch, arch):
+    """A whole model's training forward off the kernel (reduced, on this
+    CPU): its layer remat keeps what saving nothing keeps."""
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config(arch).reduced())
+    params = model.init(jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (2, 128), 0,
+                                model.cfg.vocab_size)
+
+    def loss(params):
+        return jnp.mean(model.forward(params, tokens)[0])
+
+    def saved():
+        return [(str(a.dtype), a.shape)
+                for a, _ in saved_residuals(loss, params)]
+
+    kept = saved()
+    monkeypatch.setattr(lm, "LAYER_REMAT",
+                        jax.checkpoint_policies.nothing_saveable)
+    assert kept == saved()

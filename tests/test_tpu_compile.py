@@ -15,6 +15,7 @@ only one process may load the TPU library, and every test worker
 imports this file.
 """
 
+import dataclasses
 import os
 import re
 
@@ -143,6 +144,78 @@ def test_causal_attention_compiles_for_v5e(one_chip, pass_, kernels):
                                  sharding=one_chip),
         *[jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16,
                                sharding=one_chip)] * 2, kernel=kernels)
+
+
+def _computations(text: str) -> dict:
+    """Name -> instruction lines of each computation of an HLO text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _kernels_under(comps: dict, root: str) -> set:
+    """The Pallas kernels a computation runs, through the computations
+    it calls."""
+    seen, todo, kernels = set(), [root], set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            kernels.update(re.findall(
+                r"^\s*(?:ROOT )?%(\w+)\.\d+ = .*tpu_custom_call", line))
+            for callees in re.findall(
+                    r"(?:calls|body|condition|to_apply|branch_computations)"
+                    r"=\{?([%\w.\-, ]+)", line):
+                todo += [n.strip().lstrip("%") for n in callees.split(",")]
+    return kernels
+
+
+@pytest.mark.parametrize("arch,changes,B,S", [
+    # smollm-135m's widths at 2 layers, its cell's microbatch of 8 x 2048
+    pytest.param("smollm-135m", dict(num_layers=2), 8, 2048, id="smollm"),
+    # DeepSeek-V2-Lite's MLA (q, k of 192, v of 128, YaRN scale folded
+    # into q) in 2 dense layers, its cell's microbatch of 1 x 4096
+    pytest.param("deepseek-v2-lite", dict(
+        family="dense", num_layers=2, first_k_dense=0, num_experts=0,
+        vocab_size=12800), 1, 4096, id="mla")])
+def test_layer_remat_runs_the_attention_forward_once(one_chip, monkeypatch,
+                                                     arch, changes, B, S):
+    """The train step's layer scan keeps the splash forward's output and
+    logsumexp, so its backward body runs the fused backward kernel and
+    no second forward kernel."""
+    from repro.train.step import _forward_and_loss
+
+    monkeypatch.setattr(CA, "on_tpu", lambda: True)
+    monkeypatch.setattr(CA, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config(arch), **changes)
+    assert cfg.remat and cfg.scan_layers and cfg.dtype == "bfloat16"
+    model = build_model(cfg)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one_chip)
+
+    def grads(params, tokens):
+        return jax.grad(lambda p: _forward_and_loss(
+            model, cfg, p, {"tokens": tokens})[0])(params)
+
+    comps = _computations(
+        jax.jit(grads).lower(params, tokens).compile().as_text())
+    bodies = [_kernels_under(comps, body) for body in
+              re.findall(r" while\(.*body=%?([\w.\-]+)",
+                         "\n".join(sum(comps.values(), [])))]
+    fwd, bwd = "splash_mqa_fwd_residuals", "splash_mqa_dkv_no_residuals"
+    assert sorted(sorted(b) for b in bodies if fwd in b or bwd in b) == \
+        [[bwd], [fwd]]
 
 
 def test_packed_lars_update_compiles_on_four_chip_mesh(topo, monkeypatch):
