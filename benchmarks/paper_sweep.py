@@ -18,10 +18,7 @@ through the large-batch TrainPipeline with in-jit trust-ratio
 telemetry, streams a JSONL trajectory into ``--workdir``, and is
 resumable mid-grid with ``--resume``. ``--accum-steps`` and
 ``--precision bf16`` sweep under gradient accumulation / master
-weights. ``--accum-bench`` skips the accuracy sweep and instead
-measures the execution pipeline itself — a global batch 8x the largest
-single-step microbatch, steps/s and compiled peak-memory for f32 vs
-bf16 — appending the results to ``BENCH_optimizer.json``.
+weights.
 
 ``--family lm`` runs the token-LM counterpart of the sweep (the paper's
 §6 future work): lamb/adamw/lars/sgd cells on a reduced LM config over
@@ -33,99 +30,16 @@ Usage: PYTHONPATH=src python -m benchmarks.paper_sweep [--quick]
        PYTHONPATH=src python -m benchmarks.paper_sweep --family lm \
            --optimizers lamb adamw --lr-policy sqrt \
            --lr-schedule poly_warmup
-       PYTHONPATH=src python -m benchmarks.paper_sweep --accum-bench
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import os
-import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.configs import get_config
-from repro.core import lars, schedules
 from repro.experiments import GridRunner, GridSpec, aggregate
-from repro.experiments.spec import (INIT_LR, LR_DECAY, MOMENTUM,
-                                    TRUST_COEF, WEIGHT_DECAY)
+from repro.experiments.spec import INIT_LR, TRUST_COEF
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import build_model
-from repro.train import TrainPipeline
-from repro.train.pipeline import compiled_peak_bytes
-
-
-# ------------------------------------------------- execution-pipeline bench
-
-def _bench_opt():
-    """The sweep's LARS under Table-1 hyperparameters (bench workload)."""
-    return lars(schedules.inverse_time_decay(INIT_LR, LR_DECAY),
-                momentum=MOMENTUM, weight_decay=WEIGHT_DECAY,
-                trust_coefficient=TRUST_COEF)
-
-
-def accum_bench(*, micro_batch: int = 256, accum_steps: int = 8,
-                steps: int = 10, out: str = "BENCH_optimizer.json") -> dict:
-    """Benchmark the execution pipeline itself (not accuracy): a global
-    batch ``accum_steps``x the largest single-step microbatch, run via
-    scan accumulation, for f32 vs bf16 — steps/s and compiled
-    peak-memory deltas, merged into ``out`` under
-    ``"large_batch_pipeline"`` (the optimizer bench owns the rest of the
-    file, so run this after it)."""
-    assert accum_steps >= 8, "bench contract: global >= 8x microbatch"
-    global_batch = micro_batch * accum_steps
-    cfg = get_config("lenet-mnist")
-    model = build_model(cfg)
-    rng = np.random.default_rng(0)
-    batch = {"x": jnp.asarray(rng.random((global_batch, 28, 28, 1)),
-                              jnp.float32),
-             "y": jnp.asarray(rng.integers(0, 10, global_batch), jnp.int32)}
-    rows = []
-    for precision in ("f32", "bf16"):
-        pipe = TrainPipeline(model, _bench_opt(), cfg,
-                             accum_steps=accum_steps, precision=precision)
-        state = pipe.init_state(jax.random.key(0))
-        peak = compiled_peak_bytes(pipe.lower(state, batch).compile())
-        state, m = pipe(state, batch)          # compile + warmup
-        jax.block_until_ready(m["loss"])
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            state, m = pipe(state, batch)
-        jax.block_until_ready(m["loss"])
-        dt = (time.perf_counter() - t0) / steps
-        rows.append({"precision": precision, "micro_batch": micro_batch,
-                     "accum_steps": accum_steps,
-                     "global_batch": global_batch,
-                     "steps_per_s": 1.0 / dt,
-                     "examples_per_s": global_batch / dt,
-                     "peak_bytes": peak,
-                     "loss": float(m["loss"])})
-        peak_s = f"{peak / 1e6:8.1f} MB" if peak is not None else "   n/a"
-        print(f"{precision:5s} global={global_batch} (micro={micro_batch} "
-              f"x accum={accum_steps})  {1.0 / dt:6.2f} steps/s  "
-              f"{global_batch / dt:9.0f} ex/s  peak {peak_s}", flush=True)
-
-    by = {r["precision"]: r for r in rows}
-    deltas = {"bf16_vs_f32_steps_per_s":
-              by["bf16"]["steps_per_s"] / by["f32"]["steps_per_s"] - 1.0}
-    if by["f32"]["peak_bytes"] and by["bf16"]["peak_bytes"]:
-        deltas["bf16_vs_f32_peak_bytes"] = \
-            by["bf16"]["peak_bytes"] / by["f32"]["peak_bytes"] - 1.0
-    section = {"backend": jax.default_backend(), "rows": rows,
-               "deltas": deltas}
-    payload = {}
-    if out and os.path.exists(out):
-        with open(out) as f:
-            payload = json.load(f)
-    payload["large_batch_pipeline"] = section
-    if out:
-        with open(out, "w") as f:
-            json.dump(payload, f, indent=2)
-        print(f"merged large_batch_pipeline section into {out}")
-    return section
 
 
 # ----------------------------------------------------------------- sweep
@@ -168,18 +82,7 @@ def main() -> None:
     ap.add_argument("--accum-steps", type=int, default=1,
                     help="microbatches accumulated per update in each cell")
     ap.add_argument("--precision", default="f32", choices=("f32", "bf16"))
-    ap.add_argument("--accum-bench", action="store_true",
-                    help="skip the accuracy sweep; benchmark the "
-                    "accumulation pipeline (f32 vs bf16) into "
-                    "BENCH_optimizer.json")
     args = ap.parse_args()
-
-    if args.accum_bench:
-        micro, accum = (64, 8) if args.quick else (256, 8)
-        accum_bench(micro_batch=micro, accum_steps=accum,
-                    steps=3 if args.quick else 10,
-                    out=args.out or "BENCH_optimizer.json")
-        return
 
     lm = args.family == "lm"
     if args.quick:

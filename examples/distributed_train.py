@@ -1,6 +1,6 @@
-"""Distributed training on emulated devices: the SAME sharded train step
-the production dry-run lowers, executed for real on 8 host devices
-(data=4 x model=2), with LARS trust ratios computed over sharded leaves.
+"""Distributed training on emulated devices: the sharded train step
+executed on 8 host devices (data=4 x model=2), with LARS trust ratios
+computed over sharded leaves.
 
 Run: PYTHONPATH=src python examples/distributed_train.py
 (Re-execs itself with XLA_FLAGS to expose 8 CPU devices.)

@@ -2,10 +2,7 @@
 architecture plus the paper's own CNN.
 """
 
-from repro.configs.base import ModelConfig, InputShape, param_count  # noqa: F401
-from repro.configs.shapes import (SHAPES, TRAIN_4K, PREFILL_32K,  # noqa: F401
-                                  DECODE_32K, LONG_500K,
-                                  LONG_CONTEXT_WINDOW)
+from repro.configs.base import ModelConfig, param_count  # noqa: F401
 
 from repro.configs import (whisper_base, deepseek_v2_236b,
                            deepseek_v2_lite, zamba2_7b,
@@ -28,9 +25,3 @@ def get_config(name: str) -> ModelConfig:
     if name not in ARCHS:
         raise ValueError(f"unknown arch {name!r}; have {sorted(ARCHS)}")
     return ARCHS[name]
-
-
-def get_shape(name: str) -> InputShape:
-    if name not in SHAPES:
-        raise ValueError(f"unknown shape {name!r}; have {sorted(SHAPES)}")
-    return SHAPES[name]

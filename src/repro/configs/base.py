@@ -1,6 +1,5 @@
 """Config system: a single frozen dataclass covering every assigned
-architecture family (dense / MoE / SSM / hybrid / enc-dec / VLM / CNN),
-plus the four assigned input shapes.
+architecture family (dense / MoE / SSM / hybrid / enc-dec / VLM / CNN).
 
 Every named config lives in its own ``configs/<id>.py`` module citing its
 source; ``configs/__init__.py`` is the registry (``--arch <id>``).
@@ -94,24 +93,15 @@ class ModelConfig:
     num_image_tokens: int = 0      # stub SigLIP patch embeddings
 
     # --- misc ---
-    attn_q_chunk: int = 0          # 0 = no query chunking; >0 = scan q blocks
-    flash_vjp: bool = False        # memory-lean custom-VJP attention
-                                   # (recompute-in-backward; §Perf)
     loss_chunk: int = 0            # 0 = whole-sequence logits; >0 = scan
                                    # the vocab matmul+NLL over seq chunks
                                    # (checkpointed — O(B*c*V) live logits)
-    serve_pure_tp: bool = False    # decode: drop FSDP weight shard (pure
-                                   # TP) when the model fits HBM (§Perf)
     act: str = "silu"
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     remat: bool = True
-    remat_block: int = 0           # >0: two-level (sqrt) remat — scan over
-                                   # L/b blocks of b layers; saved carries
-                                   # drop from O(L) to O(L/b + b) (§Perf)
-    scan_layers: bool = True
     source: str = ""               # citation
 
     # ------------------------------------------------------------- derived
@@ -127,16 +117,6 @@ class ModelConfig:
     @property
     def dt_rank(self) -> int:
         return self.ssm_dt_rank or -(-self.d_model // 16)
-
-    @property
-    def is_attention_free(self) -> bool:
-        return self.family == "ssm"
-
-    @property
-    def supports_long_context(self) -> bool:
-        """True if long_500k decode is sub-quadratic/bounded for this arch:
-        SSM/hybrid natively; attention archs via sliding window."""
-        return self.family in ("ssm", "hybrid") or self.sliding_window > 0
 
     def reduced(self, *, max_layers: int = 2, max_d_model: int = 256,
                 max_experts: int = 4, max_vocab: int = 512) -> "ModelConfig":
@@ -190,16 +170,8 @@ class ModelConfig:
         return dataclasses.replace(self, **changes)
 
 
-@dataclasses.dataclass(frozen=True)
-class InputShape:
-    name: str
-    seq_len: int
-    global_batch: int
-    mode: str                      # "train" | "prefill" | "decode"
-
-
 def param_count(cfg: ModelConfig) -> tuple[int, int]:
-    """(total, active) parameter counts, analytic. Used for MODEL_FLOPS."""
+    """(total, active) parameter counts, analytic."""
     d, L, V = cfg.d_model, cfg.num_layers, cfg.vocab_size
     H, Hkv, hd = cfg.attn_dims
 

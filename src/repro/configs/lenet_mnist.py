@@ -13,6 +13,5 @@ CONFIG = ModelConfig(
     act="relu",
     dtype="float32",
     remat=False,
-    scan_layers=False,
     source="Chowdhury et al. 2021 §3.1",
 )

@@ -352,7 +352,7 @@ def make_optimizer(rule: LayerwiseRule, learning_rate: float | Schedule, *,
 
     ``use_pallas="auto"`` resolves per backend (compiled megakernels on
     TPU, the jnp engine elsewhere — interpret-mode Pallas on CPU is
-    ~100x slower than the fused jnp path, see BENCH_optimizer.json);
+    ~100x slower than the fused jnp path);
     ``True``/``False`` force one path (tests, benchmarks).
 
     ``slot_dtype="int8"`` stores every rule slot as int8 codes + f32

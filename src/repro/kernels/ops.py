@@ -42,9 +42,9 @@ def resolve_use_pallas(mode: bool | str) -> bool:
 
     ``"auto"`` (the :func:`repro.core.lars` default) selects the
     compiled Pallas path only where it actually compiles — the TPU
-    backend. On CPU/GPU the kernels run through the Pallas interpreter
-    (239 ms/step vs ~2 ms for the fused jnp engine in
-    BENCH_optimizer.json), so "auto" resolves to the jnp path there —
+    backend. On CPU/GPU the kernels run through the Pallas interpreter,
+    about 100x slower than the fused jnp engine, so "auto" resolves to
+    the jnp path there —
     the same policy :func:`flash_decode` applies via its ``interpret``
     default. ``True``/``False`` force one path (kernel tests and
     benchmarks pin the interpreter explicitly).

@@ -1,2 +1,2 @@
-"""Launcher layer: production mesh, input specs, multi-pod dry-run,
-roofline analysis, and the train/serve entry points."""
+"""Launcher layer: mesh construction, config overrides, the compile
+cache, and the train/serve/experiment entry points."""

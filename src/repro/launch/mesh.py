@@ -1,8 +1,7 @@
 """Mesh construction: every mesh in the repo is built here.
 
 FUNCTIONS, not module-level constants, so importing this module never
-touches jax device state (smoke tests must see 1 CPU device; only
-dryrun.py sets the 512-host-device XLA flag).
+touches jax device state.
 
 All axes are ``AxisType.Auto``: the step functions place data with
 ``in_shardings``/``with_sharding_constraint`` and leave propagation to
@@ -28,33 +27,6 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(shape),
                          devices=devs)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: (data=16, model=16) = 256 chips.
-    Multi-pod: (pod=2, data=16, model=16) = 512 chips — the ``pod`` axis
-    carries pure data parallelism (per-step gradient all-reduce only).
-
-    When the process exposes more devices than the mesh needs (the
-    512-host-device dry-run lowering a single-pod mesh), the mesh takes
-    the leading subset."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    if len(jax.devices()) < n:
-        raise RuntimeError(
-            f"need {n} devices for mesh {dict(zip(axes, shape))}, have "
-            f"{len(jax.devices())} — run under dryrun.py (XLA host-device "
-            f"flag)")
-    return make_mesh(shape, axes)
-
-
-def make_host_mesh(*, model: int = 1):
-    """Degenerate mesh over the local device(s) — examples / smoke runs."""
-    n = len(jax.devices())
-    return make_mesh((n // model, model), ("data", "model"))
 
 
 def mesh_from_spec(spec: str, *, allow_none: bool = False):

@@ -1,11 +1,5 @@
-"""Shared ``--set FIELD=VALUE`` config-override parsing.
-
-Used by ``launch/train.py`` and ``launch/hillclimb.py`` (previously two
-copies drifting apart). Deliberately side-effect free: importing this
-module must never touch jax device state (hillclimb sets the 512-device
-XLA flag at module import, which is exactly why train.py could not
-import the parser from there).
-"""
+"""Shared ``--set FIELD=VALUE`` config-override parsing, used by
+``launch/train.py`` and ``launch/serve.py``."""
 
 from __future__ import annotations
 

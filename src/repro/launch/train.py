@@ -101,7 +101,7 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="restore a TrainState checkpoint before training")
     ap.add_argument("--set", action="append", default=[],
                     metavar="FIELD=VALUE",
-                    help="config override, e.g. --set remat_block=8")
+                    help="config override, e.g. --set loss_chunk=8")
     args = ap.parse_args(argv)
     if args.batch % args.accum_steps:
         raise SystemExit(f"--batch {args.batch} must be divisible by "

@@ -97,8 +97,7 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
                    causal: bool = True, window: int = 0,
                    prefix_len=None, kv_len=None,
                    kv_chunk: int = 1024, scale: Optional[float] = None,
-                   softcap: float = 0.0, q_chunk: int = 0,
-                   flash_vjp: bool = False) -> jnp.ndarray:
+                   softcap: float = 0.0) -> jnp.ndarray:
     """Online-softmax attention, scanned over KV chunks.
 
     q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D). q_positions: (Sq,) or
@@ -108,12 +107,9 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
     Plain causal self-attention over positions 0..S-1 (no window,
     prefix, length, KV positions or softcap) runs in the Pallas flash
     kernels on a TPU wherever :func:`repro.kernels.causal_attention.
-    supports` the shapes; that path ignores ``kv_chunk``, ``q_chunk``
-    and ``flash_vjp``, which shape only this jnp path, its oracle.
+    supports` the shapes; that path ignores ``kv_chunk``, which shapes
+    only this jnp path, its oracle.
 
-    ``q_chunk`` > 0 additionally scans over query blocks (flash-style 2-D
-    tiling): bounds the live (Sq_blk, kv_chunk) score tile — required at
-    32k-prefill scales where a full (Sq, kc) stripe per head is GBs.
     ``kv_chunk >= Sk`` collapses the KV scan into a single unrolled block
     (the decode path: scanning over a TP-sharded cache axis would force
     an all-gather per iteration; one block lets GSPMD keep KV stripes
@@ -129,31 +125,6 @@ def attention_core(q, k, v, *, q_positions, kv_positions=None,
                 and CA.supports(q, k, v, scale)):
             return CA.causal_attention(q, k, v, scale=scale)
         q_positions = jnp.arange(Sq)
-    if q_chunk and Sq > q_chunk and Sq % q_chunk == 0:
-        nq = Sq // q_chunk
-        if q_positions.ndim == 1:
-            q_positions = jnp.broadcast_to(q_positions[None], (B, Sq))
-        qs = jnp.moveaxis(q.reshape(B, nq, q_chunk, H, D), 1, 0)
-        qp = jnp.moveaxis(q_positions.reshape(B, nq, q_chunk), 1, 0)
-
-        def qblock(_, inp):
-            qb, qpb = inp
-            out = attention_core(
-                qb, k, v, q_positions=qpb, kv_positions=kv_positions,
-                causal=causal, window=window, prefix_len=prefix_len,
-                kv_len=kv_len, kv_chunk=kv_chunk, scale=scale,
-                softcap=softcap, flash_vjp=flash_vjp)
-            return None, out
-
-        _, outs = jax.lax.scan(qblock, None, (qs, qp))
-        return jnp.moveaxis(outs, 0, 1).reshape(B, Sq, H, v.shape[3])
-
-    if flash_vjp and not isinstance(kv_len, jnp.ndarray) \
-            and not isinstance(prefix_len, jnp.ndarray) \
-            and kv_positions is None:
-        from repro.models import flash_attn as FA
-        cfgt = (causal, window, prefix_len, scale, softcap, kv_len)
-        return FA.flash_attention(q, k, v, q_positions, cfgt, kv_chunk)
     Sk, Hkv = k.shape[1], k.shape[2]
     Dv = v.shape[3]                       # may differ from D (MLA)
     G = H // Hkv
@@ -230,9 +201,7 @@ def attention_block(cfg, p, x, positions, *, causal=True, prefix_len=None,
     with jax.named_scope(phases.ATTENTION_CORE):
         out = attention_core(q, k, v, q_positions=positions, causal=causal,
                              window=w, prefix_len=prefix_len,
-                             softcap=cfg.attn_logit_softcap,
-                             q_chunk=cfg.attn_q_chunk,
-                             flash_vjp=cfg.flash_vjp)
+                             softcap=cfg.attn_logit_softcap)
     return out.reshape(B, S, H * hd) @ p["wo"]
 
 

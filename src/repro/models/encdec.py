@@ -104,13 +104,7 @@ class EncDecModel:
         if cfg.remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
-        if cfg.scan_layers:
-            x, _ = jax.lax.scan(body, x, params["enc_layers"])
-        else:
-            for i in range(cfg.encoder_layers):
-                params_l = jax.tree_util.tree_map(lambda t: t[i],
-                                                  params["enc_layers"])
-                x, _ = body(x, params_l)
+        x, _ = jax.lax.scan(body, x, params["enc_layers"])
         return L.apply_norm(cfg, x, params["enc_norm"])
 
     # --------------------------------------------------------------- decoder
@@ -124,8 +118,7 @@ class EncDecModel:
         k = (enc_out @ p["wk"]).reshape(B, -1, Hkv, hd)
         v = (enc_out @ p["wv"]).reshape(B, -1, Hkv, hd)
         out = A.attention_core(q, k, v, q_positions=positions,
-                               causal=False, q_chunk=cfg.attn_q_chunk,
-                               flash_vjp=cfg.flash_vjp)
+                               causal=False)
         return out.reshape(B, S, H * hd) @ p["wo"]
 
     def _dec_layer(self, params_l, x, enc_out, positions):
@@ -161,13 +154,7 @@ class EncDecModel:
         if cfg.remat:
             body = jax.checkpoint(
                 body, policy=jax.checkpoint_policies.nothing_saveable)
-        if cfg.scan_layers:
-            x, _ = jax.lax.scan(body, x, params["dec_layers"])
-        else:
-            for i in range(cfg.num_layers):
-                params_l = jax.tree_util.tree_map(lambda t: t[i],
-                                                  params["dec_layers"])
-                x, _ = body(x, params_l)
+        x, _ = jax.lax.scan(body, x, params["dec_layers"])
         return self.logits(params, x), {"aux_loss": jnp.zeros((), jnp.float32)}
 
     # ----------------------------------------------------------------- serve
@@ -206,9 +193,7 @@ class EncDecModel:
             h = L.apply_norm(cfg, x, params_l["ln1"])
             q, k, v = A.qkv_project(cfg, params_l["self_attn"], h, positions)
             out = A.attention_core(q, k, v, q_positions=positions,
-                                   causal=True, window=cfg.sliding_window,
-                                   q_chunk=cfg.attn_q_chunk,
-                                   flash_vjp=cfg.flash_vjp)
+                                   causal=True, window=cfg.sliding_window)
             x = x + out.reshape(B, S, H * hd) @ params_l["self_attn"]["wo"]
             h = L.apply_norm(cfg, x, params_l["ln_x"])
             x = x + self._cross_attend(params_l, h, enc_out, positions)
@@ -220,16 +205,7 @@ class EncDecModel:
             return x, {"k": _fit(k, s_buf, axis=1), "v": _fit(v, s_buf, axis=1),
                        "xk": xk, "xv": xv}
 
-        if cfg.scan_layers:
-            x, ys = jax.lax.scan(body, x, params["dec_layers"])
-        else:
-            outs = []
-            for i in range(cfg.num_layers):
-                params_l = jax.tree_util.tree_map(lambda t: t[i],
-                                                  params["dec_layers"])
-                x, kv_out = body(x, params_l)
-                outs.append(kv_out)
-            ys = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
+        x, ys = jax.lax.scan(body, x, params["dec_layers"])
         k_fit, v_fit = ys["k"], ys["v"]
         if cfg.sliding_window and S > s_buf:
             k_fit = jnp.roll(k_fit, S % s_buf, axis=2)
@@ -267,17 +243,7 @@ class EncDecModel:
             return (x,), {"k": k, "v": v}
 
         layer_cache = {k: cache[k] for k in ("k", "v", "xk", "xv")}
-        if cfg.scan_layers:
-            (x,), new_kv = jax.lax.scan(
-                body, (x,), (params["dec_layers"], layer_cache))
-        else:
-            carry, outs = (x,), []
-            for i in range(cfg.num_layers):
-                sl = jax.tree_util.tree_map(
-                    lambda t: t[i], (params["dec_layers"], layer_cache))
-                carry, kv = body(carry, sl)
-                outs.append(kv)
-            (x,) = carry
-            new_kv = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
+        (x,), new_kv = jax.lax.scan(
+            body, (x,), (params["dec_layers"], layer_cache))
         new_cache = dict(cache, k=new_kv["k"], v=new_kv["v"], pos=pos + 1)
         return self.logits(params, x), new_cache

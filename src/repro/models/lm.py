@@ -3,8 +3,7 @@ hybrid / VLM families, assembled from the shared blocks.
 
 Key structural decisions:
   * per-layer params are STACKED on a leading (L, ...) axis and the layer
-    loop is a `lax.scan` — keeps HLO size O(1) in depth (mandatory for
-    compiling 60-81-layer configs 80 times in the dry-run) and is what the
+    loop is a `lax.scan` — keeps HLO size O(1) in depth and is what the
     LARS `stacked` marker machinery exists for;
   * remat (`jax.checkpoint`) wraps the scan body, saving only the attention
     kernel's output and logsumexp (`causal_attention.RESIDUALS`) beside the
@@ -148,27 +147,19 @@ class LanguageModel:
         head = [(params["dense_layers"], 0, k)] if k else []
         return head + [(params["layers"], k, self.cfg.num_layers - k)]
 
-    def _scan_layers(self, body, carry, params, per_layer=()):
+    def _layer_scan(self, body, carry, params, per_layer=()):
         """``carry, y = body(carry, (layer params, *per-layer slices,
-        layer id))`` over every layer, stack by stack, as a ``lax.scan``
-        (or unrolled without ``scan_layers``). ``per_layer``: arrays with
-        a leading num_layers axis. Returns (carry, ys over all layers)."""
+        layer id))`` over every layer, stack by stack, as a ``lax.scan``.
+        ``per_layer``: arrays with a leading num_layers axis. Returns
+        (carry, ys over all layers)."""
         stacks = self._stacks(params)
         outs = []
         for sp, lo, n in stacks:
             sl = tuple(per_layer) if len(stacks) == 1 else tuple(
                 jax.tree_util.tree_map(lambda t: t[lo:lo + n], a)
                 for a in per_layer)
-            if self.cfg.scan_layers:
-                carry, ys = jax.lax.scan(
-                    body, carry, (sp, *sl, jnp.arange(lo, lo + n)))
-            else:   # unrolled: exact per-layer cost accounting
-                ys = []
-                for i in range(n):
-                    xs = jax.tree_util.tree_map(lambda t: t[i], (sp, *sl))
-                    carry, y = body(carry, (*xs, jnp.asarray(lo + i)))
-                    ys.append(y)
-                ys = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
+            carry, ys = jax.lax.scan(
+                body, carry, (sp, *sl, jnp.arange(lo, lo + n)))
             outs.append(ys)
         if len(outs) == 1:
             return carry, outs[0]
@@ -268,35 +259,9 @@ class LanguageModel:
         if cfg.remat:
             body = jax.checkpoint(body, policy=LAYER_REMAT)
         carry = (x, aux_zero(cfg))
-        blk = cfg.remat_block
         for stack, lo, n in self._stacks(params):
-            if cfg.scan_layers and cfg.remat and blk and n % blk == 0:
-                # sqrt-remat: outer scan over n/b checkpointed blocks,
-                # inner scan over b layers. Saved residual-stream carries
-                # drop from n slices to n/b (+ b transiently inside one
-                # block's backward) — the flat-scan carry stack (f32+bf16
-                # copies) dominates peak train memory at depth 60-81
-                # (§Perf).
-                nb = n // blk
-                params_b = jax.tree_util.tree_map(
-                    lambda t: t.reshape((nb, blk) + t.shape[1:]), stack)
-                idx_b = jnp.arange(lo, lo + n).reshape(nb, blk)
-
-                def outer(c, inp):
-                    pb, ib = inp
-                    c, _ = jax.lax.scan(body, c, (pb, ib))
-                    return c, None
-
-                outer = jax.checkpoint(
-                    outer, policy=jax.checkpoint_policies.nothing_saveable)
-                carry, _ = jax.lax.scan(outer, carry, (params_b, idx_b))
-            elif cfg.scan_layers:
-                carry, _ = jax.lax.scan(
-                    body, carry, (stack, jnp.arange(lo, lo + n)))
-            else:   # unrolled: exact per-layer cost accounting (probes)
-                for i in range(n):
-                    params_l = jax.tree_util.tree_map(lambda t: t[i], stack)
-                    carry, _ = body(carry, (params_l, jnp.asarray(lo + i)))
+            carry, _ = jax.lax.scan(
+                body, carry, (stack, jnp.arange(lo, lo + n)))
         x, aux = carry
         if "moe_load_max_over_mean" in aux:     # a mean over MoE layers
             n_moe = cfg.num_layers - cfg.first_k_dense
@@ -444,7 +409,7 @@ class LanguageModel:
                 shared_cache, use_flash=use_flash)
             return (x, shared_cache), new_cache_l
 
-        (x, shared_cache), new_layer_cache = self._scan_layers(
+        (x, shared_cache), new_layer_cache = self._layer_scan(
             body, (x, shared_cache), params, (layer_cache,))
 
         new_cache = dict(new_layer_cache)
@@ -504,8 +469,7 @@ class LanguageModel:
                 out = A.attention_core(
                     q, k, v, q_positions=None, causal=True,
                     window=cfg.sliding_window, prefix_len=prefix_len,
-                    softcap=cfg.attn_logit_softcap,
-                    q_chunk=cfg.attn_q_chunk, flash_vjp=cfg.flash_vjp)
+                    softcap=cfg.attn_logit_softcap)
                 H, Hkv, hd = cfg.attn_dims
                 out = out.reshape(B, S, H * hd) @ params_l["attn"]["wo"]
                 kv_out = {"k": k, "v": v}
@@ -518,7 +482,7 @@ class LanguageModel:
                 x = x + mlp_block(cfg, params_l["mlp"], h)
             return (x,), kv_out
 
-        (x,), kvs = self._scan_layers(body, (x,), params)
+        (x,), kvs = self._layer_scan(body, (x,), params)
 
         logits = self._last_valid_logits(params, x, lengths)
         if cfg.use_mla:
@@ -683,7 +647,7 @@ class LanguageModel:
                 shared_cache)
             return (x, shared_cache), new_cache_l
 
-        (x, shared_cache), new_layer_cache = self._scan_layers(
+        (x, shared_cache), new_layer_cache = self._layer_scan(
             body, (x, shared_cache), params, (layer_cache,))
 
         new_cache = dict(new_layer_cache)
@@ -796,8 +760,7 @@ class LanguageModel:
                     q, k, v = A.qkv_project(cfg, shared["attn"], h, positions)
                     out = A.attention_core(
                         q, k, v, q_positions=None, causal=True,
-                        window=cfg.sliding_window,
-                        q_chunk=cfg.attn_q_chunk, flash_vjp=cfg.flash_vjp)
+                        window=cfg.sliding_window)
                     x = x + out.reshape(B, S, H * hd) @ shared["attn"]["wo"]
                     hh = L.apply_norm(cfg, x, shared["ln2"])
                     x = x + mlp_block(cfg, shared["mlp"], hh)
@@ -814,19 +777,9 @@ class LanguageModel:
                 ys["vv"] = vv
             return (x,), ys
 
-        if cfg.scan_layers:
-            (x,), ys = jax.lax.scan(body, (x,),
-                                    (params["layers"],
-                                     jnp.arange(cfg.num_layers)))
-        else:
-            carry, outs = (x,), []
-            for i in range(cfg.num_layers):
-                params_l = jax.tree_util.tree_map(lambda t: t[i],
-                                                  params["layers"])
-                carry, y_out = body(carry, (params_l, jnp.asarray(i)))
-                outs.append(y_out)
-            (x,) = carry
-            ys = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
+        (x,), ys = jax.lax.scan(body, (x,),
+                                (params["layers"],
+                                 jnp.arange(cfg.num_layers)))
         cache["conv"] = ys["conv"].astype(cache["conv"].dtype)
         cache["h"] = ys["h"]
         if hybrid:

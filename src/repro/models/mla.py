@@ -110,9 +110,7 @@ def mla_block(cfg, p, x, positions) -> jnp.ndarray:
 
     with jax.named_scope(phases.ATTENTION_CORE):
         out = attention_core(q, k, v, q_positions=positions, causal=True,
-                             scale=1.0,
-                             q_chunk=cfg.attn_q_chunk,
-                             flash_vjp=cfg.flash_vjp)
+                             scale=1.0)
     return out.reshape(B, S, H * vd) @ p["wo"]
 
 

@@ -1,7 +1,7 @@
 """Serving runtime: slot-paged persistent KV/SSM cache, bounded
 request schedulers (FIFO and SLO-aware priority/preemption), in-jit
 sampling, and the continuous-batching engine (plus the legacy
-static-batch engine and dry-run step factories)."""
+static-batch engine and its step factories)."""
 
 from repro.serve.cache import SlotCache  # noqa: F401
 from repro.serve.engine import (DecodeEngine, ServeEngine,  # noqa: F401

@@ -1,7 +1,7 @@
 """Serving engines: the continuous-batching :class:`ServeEngine` (slot
 cache + scheduler + in-jit sampling) and the legacy static-batch
-:class:`DecodeEngine` (kept as the benchmark baseline), plus the
-prefill/serve step factories used by the dry-run harness.
+:class:`DecodeEngine` (used by tests and ``examples/quickstart.py``),
+plus the prefill/serve step factories it jits.
 
 ServeEngine contract (the decode hot path):
   * ONE jitted call per emitted token, for the whole slot batch, with
@@ -76,8 +76,7 @@ def make_prefill_step(model, cfg=None) -> Callable:
 def make_serve_step(model, cfg=None) -> Callable:
     """(params, cache, tokens (B,1)) -> (logits (B,1,V), new cache).
 
-    ONE new token against the standing cache — the decode_32k / long_500k
-    dry-run workload.
+    ONE new token against the standing cache.
     """
     cfg = cfg if cfg is not None else model.cfg
 

@@ -1,6 +1,6 @@
 """Serve-side experiment path: declarative traffic scenarios ->
-per-scenario TTFT/latency percentiles + throughput -> ``BENCH_serve.json``
-(the serve twin of ``experiments/record.py``/``report.py``).
+per-scenario TTFT/latency percentiles + throughput (the serve twin of
+``experiments/record.py``/``report.py``).
 
 A :class:`ServeScenario` names an engine configuration plus traffic as
 WAVES of requests (the engine drains between waves — wave 2 can hit

@@ -396,8 +396,8 @@ class TrainPipeline:
 
     def lower(self, state: TrainState, batch):
         """``jax.stages.Lowered`` for this step — compile-time
-        introspection (``.compile().memory_analysis()`` drives the
-        peak-memory deltas reported by ``benchmarks/paper_sweep.py``)."""
+        introspection (``.compile().memory_analysis()`` gives its peak
+        memory, see :func:`compiled_peak_bytes`)."""
         fn = self._jit(state, batch)
         if self.mesh is not None:
             with jax.set_mesh(self.mesh):

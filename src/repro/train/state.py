@@ -7,7 +7,7 @@ state is born on the flat-packed layer-wise substrate: slot buffers
 the OptState carries the static PackedLayout as pytree metadata. Pass
 ``packed=False`` to keep per-leaf slot pytrees instead — the reference
 layout used when slots must shard leaf-for-leaf alongside FSDP params
-(the pjit dry-run path builds its states that way via ``opt.init(p)``).
+(``opt.init(p)``).
 
 Memory trade-off under pjit: the packed superbuffers (params/grads
 repacked per step, slots persistent) are REPLICATED per device — right

@@ -1,11 +1,9 @@
-"""Dry-run machinery unit tests (no 512-device compiles): MODEL_FLOPS
-accounting, probe extrapolation linearity, reduced-config invariants."""
+"""Config registry tests: analytic parameter counts against the
+published sizes, and the reduced-config invariants."""
 
-import jax
 import pytest
 
-from repro.configs import ARCHS, get_config, get_shape, param_count
-from repro.launch.dryrun import _model_flops
+from repro.configs import ARCHS, get_config, param_count
 
 
 def test_param_count_orders_of_magnitude():
@@ -44,23 +42,6 @@ def test_moe_active_less_than_total():
         assert active < 0.5 * total    # top-k of many experts
 
 
-def test_model_flops_train_vs_decode():
-    cfg = get_config("qwen3-14b")
-    f_train = _model_flops(cfg, get_shape("train_4k"))
-    f_dec = _model_flops(cfg, get_shape("decode_32k"))
-    # train: 6*N*B*S; decode: 2*N*B*1
-    assert f_train / f_dec == pytest.approx(
-        (6 * 4096 * 256) / (2 * 128), rel=1e-6)
-
-
-def test_model_flops_excludes_lookup_table():
-    cfg = get_config("minitron-8b")             # untied, 256k vocab
-    total, active = param_count(cfg)
-    f = _model_flops(cfg, get_shape("train_4k"))
-    n_used = f / (6 * 4096 * 256)
-    assert n_used == pytest.approx(active - cfg.vocab_size * cfg.d_model)
-
-
 def test_reduced_configs_within_caps():
     for name, cfg in ARCHS.items():
         r = cfg.reduced()
@@ -71,11 +52,3 @@ def test_reduced_configs_within_caps():
         if cfg.num_heads:
             assert r.num_heads % max(r.num_kv_heads, 1) == 0
 
-
-def test_probe_extrapolation_is_exactly_linear():
-    """The two-depth linear extrapolation recovers a linear cost model."""
-    k1, k2, L = 2, 4, 60
-    base, per = 7.0, 3.5
-    c1, c2 = base + k1 * per, base + k2 * per
-    total = c1 + (L - k1) * (c2 - c1) / (k2 - k1)
-    assert total == pytest.approx(base + L * per)

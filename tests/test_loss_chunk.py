@@ -36,32 +36,6 @@ def test_chunked_equals_full(chunk):
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_remat_block_matches_flat_scan():
-    """sqrt-remat (remat_block) is a pure memory transform — identical
-    loss and gradients to the flat layer scan."""
-    cfg = get_config("smollm-135m").reduced()
-    toks = jnp.asarray(np.random.default_rng(2).integers(
-        0, cfg.vocab_size, (2, 16)), jnp.int32)
-    out = {}
-    for blk in (0, 1, 2):
-        c = dataclasses.replace(cfg, remat_block=blk)
-        model = build_model(c)
-        params = model.init(jax.random.key(0))
-
-        def loss_fn(p):
-            logits, _ = model.forward(p, toks)
-            return lm_loss(logits, toks)
-
-        out[blk] = jax.value_and_grad(loss_fn)(params)
-    for blk in (1, 2):
-        np.testing.assert_allclose(float(out[blk][0]), float(out[0][0]),
-                                   rtol=1e-6)
-        for a, b in zip(jax.tree_util.tree_leaves(out[blk][1]),
-                        jax.tree_util.tree_leaves(out[0][1])):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-5, atol=1e-6)
-
-
 @pytest.mark.parametrize("arch", ["smollm-135m", "paligemma-3b"])
 def test_step_level_chunked_loss_matches(arch):
     cfg = get_config(arch).reduced()

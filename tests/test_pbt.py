@@ -141,11 +141,10 @@ def test_forced_divergence_cell_stays_strict_json(tmp_path):
 
 
 def test_committed_reports_are_strict_json():
-    """Every committed EXPERIMENTS_*/BENCH_* json must parse under a
-    strict reader (json.load accepts NaN/Infinity tokens by default, so
-    this is a REAL check, not a formality)."""
-    paths = sorted(glob.glob(os.path.join(REPO_ROOT, "EXPERIMENTS_*.json"))
-                   + glob.glob(os.path.join(REPO_ROOT, "BENCH_*.json")))
+    """Every committed EXPERIMENTS_* json must parse under a strict
+    reader (json.load accepts NaN/Infinity tokens by default, so this is
+    a REAL check, not a formality)."""
+    paths = sorted(glob.glob(os.path.join(REPO_ROOT, "EXPERIMENTS_*.json")))
     assert paths, "no committed report files found"
     for path in paths:
         _strict_loads(open(path).read())

@@ -55,7 +55,7 @@ def no_persistent_cache():
 def compiled_step(accum_steps: int, use_pallas, slot_dtype: str = "f32",
                   arch: str = "smollm-135m"):
     cfg = get_config(arch).reduced()
-    assert cfg.remat and cfg.scan_layers
+    assert cfg.remat
     model = build_model(cfg)
     pipe = TrainPipeline(model, lars(0.1, use_pallas=use_pallas,
                                      slot_dtype=slot_dtype),

@@ -36,6 +36,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 sys.path.insert(0, str(Path(__file__).parent))
 from _hypothesis_compat import given, settings, st  # noqa: E402
@@ -264,6 +265,32 @@ def test_adam_bias_correction_holds_at_int8(packed, opt_name):
                 rtol=8e-2, err_msg=f"nu bias correction, step {t}")
 
 
+def _slot_nbytes(state) -> int:
+    """Bytes of the rule's own slots and their scales; the master and
+    packed weights are not governed by ``slot_dtype``."""
+    skip = {packing.MASTER_SLOT, packing.WEIGHT_SLOT}
+    return sum(x.nbytes for k, v in state.slots.items() if k not in skip
+               for x in tree_leaves(v))
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["per_leaf", "packed"])
+@pytest.mark.parametrize("opt_name", sorted(OPTS))
+def test_int8_slots_take_under_0_30x_the_f32_bytes(opt_name, packed):
+    """int8 codes plus their f32 scales hold the slots of a model-shaped
+    tree (embeddings, a layer stack) in at most 0.30x the f32 bytes."""
+    L, d = 4, 128
+    params = {"embed": jnp.zeros((512, d)), "unembed": jnp.zeros((d, 512)),
+              "layers": {"wq": jnp.zeros((L, d, d)),
+                         "wi": jnp.zeros((L, d, 4 * d)),
+                         "scale": jnp.ones((L, d))}}
+    marker = {"embed": False, "unembed": False,
+              "layers": {"wq": True, "wi": True, "scale": True}}
+    nbytes = {dt: _slot_nbytes(OPTS[opt_name](dt).init(
+        params, stacked=marker if packed else None))
+        for dt in ("f32", "int8")}
+    assert 0 < nbytes["int8"] <= 0.30 * nbytes["f32"], nbytes
+
+
 # ------------------------------------------------------ kernel dispatch
 
 def test_resolve_use_pallas_modes():
@@ -276,7 +303,7 @@ def test_resolve_use_pallas_modes():
 def test_auto_dispatch_takes_jnp_engine_off_tpu():
     """lars() defaults to use_pallas="auto": on this CPU host the whole
     update must trace with ZERO pallas_call launches (the interpreted
-    kernels are ~100x the jnp engine — see BENCH_optimizer.json)."""
+    kernels are ~100x the jnp engine)."""
     if jax.default_backend() == "tpu":
         import pytest
         pytest.skip("auto resolves to the compiled kernels on TPU")

@@ -19,7 +19,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
 from repro.distributed import param_pspecs, cache_pspecs
 from repro.launch.mesh import make_mesh
-from repro.launch.specs import param_shapes
 from repro.models import build_model
 
 
@@ -32,7 +31,7 @@ def _leaf(tree, *path):
 def test_dense_param_specs():
     cfg = get_config("qwen3-14b")
     model = build_model(cfg)
-    specs = param_pspecs(cfg, param_shapes(model))
+    specs = param_pspecs(cfg, jax.eval_shape(model.init, jax.random.key(0)))
     assert _leaf(specs, "embed") == P("model", None)
     assert _leaf(specs, "unembed") == P(None, "model")
     # stacked layers get the leading None (layer axis scanned, not sharded)
@@ -45,7 +44,7 @@ def test_dense_param_specs():
 def test_moe_param_specs_expert_parallel():
     cfg = get_config("deepseek-v2-236b")
     model = build_model(cfg)
-    specs = param_pspecs(cfg, param_shapes(model))
+    specs = param_pspecs(cfg, jax.eval_shape(model.init, jax.random.key(0)))
     assert _leaf(specs, "layers", "moe", "wi") == P(None, "model", "data",
                                                     None)
     assert _leaf(specs, "layers", "moe", "router") == P(None, None, None)
@@ -57,7 +56,7 @@ def test_moe_param_specs_expert_parallel():
 def test_ssm_param_specs_channel_shard():
     cfg = get_config("falcon-mamba-7b")
     model = build_model(cfg)
-    specs = param_pspecs(cfg, param_shapes(model))
+    specs = param_pspecs(cfg, jax.eval_shape(model.init, jax.random.key(0)))
     assert _leaf(specs, "layers", "ssm", "in_proj") == P(None, "data",
                                                          "model")
     assert _leaf(specs, "layers", "ssm", "out_proj") == P(None, "model",
@@ -78,7 +77,7 @@ def test_divisibility_guard_drops_axis():
     class FakeMesh:
         shape = {"data": 16, "model": 16}
         axis_names = ("data", "model")
-    specs = param_pspecs(cfg, param_shapes(model), FakeMesh())
+    specs = param_pspecs(cfg, jax.eval_shape(model.init, jax.random.key(0)), FakeMesh())
     assert _leaf(specs, "embed") == P(None, None)          # 51865 % 16 != 0
     assert _leaf(specs, "dec_layers", "self_attn", "wq") == \
         P(None, "data", "model")
@@ -97,7 +96,7 @@ def test_cache_specs_decode():
     assert specs["pos"] == P(("data",))
 
 
-def test_serve_pure_tp_strips_data_axis():
+def test_serve_param_pspecs_strip_data_axis_when_tp_fits():
     """qwen2 fits TP-only -> data axis stripped; deepseek doesn't -> kept."""
     from repro.distributed.sharding import serve_param_pspecs
 
@@ -107,14 +106,14 @@ def test_serve_pure_tp_strips_data_axis():
 
     cfg = get_config("qwen2-72b")
     model = build_model(cfg)
-    shapes = param_shapes(model)
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
     specs = serve_param_pspecs(cfg, shapes, FakeMesh())
     assert _leaf(specs, "layers", "attn", "wq") == P(None, None, "model")
     assert _leaf(specs, "layers", "mlp", "wo") == P(None, "model", None)
 
     big = get_config("deepseek-v2-236b")
     bmodel = build_model(big)
-    bspecs = serve_param_pspecs(big, param_shapes(bmodel), FakeMesh())
+    bspecs = serve_param_pspecs(big, jax.eval_shape(bmodel.init, jax.random.key(0)), FakeMesh())
     # 472 GB / 16-way TP = 30 GB/device > budget -> training sharding kept
     assert _leaf(bspecs, "layers", "attn", "v_up") == P(None, "data",
                                                         "model")

@@ -197,7 +197,7 @@ def test_layer_remat_runs_the_attention_forward_once(one_chip, monkeypatch,
     monkeypatch.setattr(CA, "on_tpu", lambda: True)
     monkeypatch.setattr(CA, "default_interpret", lambda: False)
     cfg = dataclasses.replace(get_config(arch), **changes)
-    assert cfg.remat and cfg.scan_layers and cfg.dtype == "bfloat16"
+    assert cfg.remat and cfg.dtype == "bfloat16"
     model = build_model(cfg)
     params = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
